@@ -19,12 +19,6 @@ different sweep would recompile inside the timed region.
 """
 from __future__ import annotations
 
-# Must precede the first jax import in the process: the sweep step is
-# thunk-dispatch bound on CPU without the legacy emitter (~3x).
-from repro.xlaenv import tune_cpu_for_scan_sweeps
-
-tune_cpu_for_scan_sweeps()
-
 import argparse
 import dataclasses
 import sys
@@ -57,47 +51,16 @@ def _mix_spec(T: int, duration_us: float, seed: int = 0):
                         duration_us=duration_us, seed=seed)
 
 
-def _host_one(spec, *, record_completions: bool = False):
-    """One replica on the host batched datapath (the device's oracle)."""
-    from repro.api.runtime import build_traces
-    from repro.core.slo import ECTX
-    from repro.sim.fastpath import build_simulator
-    tenants = [ECTX(tenant_id=i, name=t.name, slo=t.slo(),
-                    kernel=t.workload.build())
-               for i, t in enumerate(spec.tenants)]
-    sim = build_simulator(tenants, datapath="batched",
-                          scheduler=spec.scheduler, frag=spec.frag(),
-                          arb=spec.arbiter,
-                          fifo_capacity=spec.fifo_capacity,
-                          record_completions=record_completions)
-    ta = build_traces(spec, arrays=True)
-    horizon = spec.horizon_us * 1e3 if spec.horizon_us else None
-    return sim.run(ta, horizon=horizon)
-
-
 def _parity(spec) -> bool:
     """Device == host on decisions, EQ stream and telemetry sums."""
-    from repro.sim.devicepath import run_device
-    h = _host_one(spec, record_completions=True)
-    d = run_device(spec, record_completions=True)
-    if d.time != h.time or d.completions != h.completions:
-        return False
-    if ([(e.tenant, e.kind, e.time) for e in d.events]
-            != [(e.tenant, e.kind, e.time) for e in h.events]):
-        return False
-    for i in range(len(spec.tenants)):
-        hs, ds = h.stats[i], d.stats[i]
-        if any(getattr(ds, f) != getattr(hs, f)
-               for f in ("completed", "killed", "drops",
-                         "served_payload_bytes", "last_completion",
-                         "kernel_time_count", "kernel_time_sum")):
-            return False
-    return True
+    from repro.sim.devicepath import host_oracle, parity_mismatches, run_device
+    return not parity_mismatches(spec, host_oracle(spec),
+                                 run_device(spec, record_completions=True))
 
 
 def _measure(R: int, H: int, duration_us: float):
     """(pkts_per_replica, compile_s, device_s, host_s_per_replica)."""
-    from repro.sim.devicepath import run_sweep_specs
+    from repro.sim.devicepath import host_oracle, run_sweep_specs
     base = _mix_spec(MIX_TENANTS, duration_us)
     specs = [dataclasses.replace(base, seed=s) for s in range(R)]
     # cold launch = trace + compile + run; warming with a smaller sweep
@@ -111,7 +74,7 @@ def _measure(R: int, H: int, duration_us: float):
     n_pkts = sum(st.completed for st in res[0].stats.values())
     t0 = time.perf_counter()
     for s in specs[:H]:
-        _host_one(s)
+        host_oracle(s, record_completions=False)
     host_s = (time.perf_counter() - t0) / H
     return n_pkts, cold_s, dev_s, host_s
 

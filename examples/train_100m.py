@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.training import checkpoint as CKPT
 from repro.training.data import make_pipeline
 from repro.training.trainer import build_trainer
@@ -44,7 +45,7 @@ def main():
     mesh = None
     if args.mesh != "none":
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
 
     trainer = build_trainer(cfg, mesh=mesh, total_steps=args.steps,
                             warmup_steps=20, grad_accum=args.grad_accum)

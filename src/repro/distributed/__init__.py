@@ -1,6 +1,5 @@
 """Distribution layer: mesh-aware sharding rules, overlap-friendly
 collectives, gradient compression, and pipeline parallelism."""
-from repro.distributed.compat import shard_map
 from repro.distributed.sharding import (
     batch_pspec, constrain, input_pspecs, logical_to_pspec, param_pspecs,
     shardings_for, ShardingRules,
@@ -8,5 +7,5 @@ from repro.distributed.sharding import (
 
 __all__ = [
     "batch_pspec", "constrain", "input_pspecs", "logical_to_pspec",
-    "param_pspecs", "shard_map", "shardings_for", "ShardingRules",
+    "param_pspecs", "shardings_for", "ShardingRules",
 ]

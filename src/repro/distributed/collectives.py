@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.distributed.compat import axis_size
-
 
 def _ring_perm(n: int, shift: int = 1):
     return [(i, (i + shift) % n) for i in range(n)]
@@ -33,7 +31,7 @@ def collective_matmul_ag(x: jnp.ndarray, w_shard: jnp.ndarray,
     ``axis_name``.  Each iteration multiplies the currently-held shard
     against the matching x columns while rotating shards ring-wise.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     blk = w_shard.shape[0]
 
@@ -70,7 +68,7 @@ def reduce_scatter_matmul(x_shard: jnp.ndarray, w_shard: jnp.ndarray,
     neighbour transfer.  Equivalent to psum_scatter(x @ w) over the last
     dim.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     part = x_shard @ w_shard                             # (..., d_out)
     d_out = part.shape[-1]
@@ -96,7 +94,7 @@ def all_gather_interleaved(shard: jnp.ndarray, axis_name: str,
                            tile_fn) -> jnp.ndarray:
     """Generic overlap driver: applies ``tile_fn(i, shard_i)`` as shards
     arrive ring-wise and sums the results."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     out = tile_fn((idx + 0) % n, shard)
     cur = shard

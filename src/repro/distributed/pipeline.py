@@ -19,9 +19,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.distributed.compat import shard_map
 
 
 def stage_params(params_stacked: Any, num_stages: int) -> Any:

@@ -191,10 +191,14 @@ def _rounds_pallas(prio, queue_len, cur_occup, total_occup, bvt, free_k, *,
 def wlbvt_select_rounds(prio, queue_len, cur_occup, total_occup, bvt,
                         free_k, *, num_pus: int, max_picks: int,
                         impl: str = "", interpret: bool = False):
-    """Backend switch (``attn_impl`` idiom): '' picks pallas on TPU and
-    the early-exit jnp path elsewhere."""
+    """Backend switch (``attn_impl`` idiom): '' picks pallas on TPU with
+    f32 lanes and the early-exit jnp path otherwise (the TPU kernel
+    compiler has no 64-bit types).  Interpret mode is a CPU-only
+    decision; on any other backend the kernel is compiled."""
+    backend = jax.default_backend()
     if not impl:
-        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        impl = ("pallas" if backend == "tpu" and prio.dtype == jnp.float32
+                else "jnp")
     if impl == "jnp":
         return _rounds_jnp(prio, queue_len, cur_occup, total_occup, bvt,
                            free_k, num_pus=num_pus, max_picks=max_picks)
@@ -204,9 +208,14 @@ def wlbvt_select_rounds(prio, queue_len, cur_occup, total_occup, bvt,
                                        num_pus=num_pus,
                                        max_picks=max_picks)
     if impl == "pallas":
+        interpret = interpret or backend == "cpu"
+        if not interpret and prio.dtype == jnp.float64:
+            raise ValueError(
+                "pallas wlbvt_select compiles f32 lanes only (the TPU "
+                "kernel compiler has no 64-bit types); use impl='jnp' for "
+                "f64 lanes (precision='exact') or precision='fast'")
         return _rounds_pallas(prio, queue_len, cur_occup, total_occup, bvt,
                               free_k, num_pus=num_pus, max_picks=max_picks,
-                              interpret=interpret
-                              or jax.default_backend() == "cpu")
+                              interpret=interpret)
     raise ValueError(f"unknown wlbvt_select impl {impl!r} "
                      "(expected jnp | jnp_ref | pallas)")

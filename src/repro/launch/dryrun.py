@@ -1,8 +1,10 @@
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # ^ MUST precede any jax import: jax locks the device count on first init.
 # The 512 host devices exist only for this dry-run driver; tests and
-# benchmarks see the real single CPU device.
+# benchmarks see the real single CPU device.  This tool compiles on
+# virtual CPU devices only, so on a machine with a TPU it never takes it.
 """Multi-pod dry-run: .lower().compile() every (arch x shape x mesh) cell.
 
 For each cell this driver lowers the real jitted program (train_step for

@@ -16,7 +16,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.distributed import sharding as SH
@@ -27,16 +27,27 @@ HBM_BW = 819e9                    # bytes/s per chip
 ICI_BW = 50e9                     # bytes/s per link
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules in
+    ``distributed/sharding.py`` are ``with_sharding_constraint`` /
+    ``NamedSharding`` hints for the partitioner, which jax rejects on the
+    ``Explicit`` axes ``make_mesh`` defaults to."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape: Tuple[int, ...] = (2, 4),
                    axes: Tuple[str, ...] = ("data", "model")) -> Mesh:
     """Small mesh over forced host devices (tests)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,8 @@ import json
 import os
 import sys
 
+from repro.compile_cache import use_persistent_cache
+
 
 def _parse_sets(pairs):
     out = {}
@@ -125,6 +127,7 @@ def run_one(name: str, backend: str, params, *, arch: str = "",
 
 
 def main(argv=None) -> int:
+    use_persistent_cache()
     ap = argparse.ArgumentParser(
         description="run a registered OSMOSIS scenario -> RunReport")
     ap.add_argument("scenario", nargs="?", default="",

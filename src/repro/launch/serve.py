@@ -16,8 +16,11 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.compile_cache import use_persistent_cache
+
 
 def main(argv=None) -> int:
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -24,9 +24,7 @@ import json
 import sys
 import time
 
-# Must precede any import that pulls in jax: the sweep inner loop is
-# thunk-dispatch bound on CPU without the legacy emitter.
-from repro.xlaenv import tune_cpu_for_scan_sweeps
+from repro.compile_cache import use_persistent_cache
 
 
 def _parse_axis(arg: str):
@@ -88,7 +86,7 @@ def run_sweep(sweep, *, impl: str = "", precision: str = "exact"):
 
 
 def main(argv=None) -> int:
-    tune_cpu_for_scan_sweeps()
+    use_persistent_cache()
     ap = argparse.ArgumentParser(
         description="run a scenario sweep on the device datapath")
     ap.add_argument("scenario", nargs="?", default="",

@@ -38,6 +38,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro.configs import get_config, smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.training import checkpoint as CKPT
     from repro.training.data import make_pipeline
     from repro.training.trainer import build_trainer
@@ -46,7 +47,7 @@ def main(argv=None) -> int:
     mesh = None
     if args.mesh != "none":
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
 
     trainer = build_trainer(cfg, mesh=mesh, total_steps=args.steps,
                             grad_accum=args.grad_accum)

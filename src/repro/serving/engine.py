@@ -104,7 +104,7 @@ class ModelExecutor:
         return np.asarray(nxt)
 
     def decode(self, tokens, lengths, active):
-        nxt, self.cache = self.fns.decode(
+        nxt, _, self.cache = self.fns.decode(
             self.params, self.cache, self.jnp.asarray(tokens),
             self.jnp.asarray(lengths), self.jnp.asarray(active))
         return np.asarray(nxt)

@@ -8,7 +8,7 @@ programs the engine (and the dry-run) calls:
     Ragged tails are exact: pad entries are written with position -1 and
     recurrent state is untouched past valid_n (see models' ``valid`` path).
   * ``decode(params, cache, tokens(B,), lengths(B,), active(B,))``
-      -> (next_token (B,), cache)
+      -> (next_token (B,), logits (B,V), cache)
   * ``reset_slots(cache, keep_mask(B,))`` — zero/invalidate freed slots'
     cache rows so re-assigned slots never attend to a previous tenant's KV
     (the paper's memory-isolation requirement R3 at the cache level).
@@ -40,7 +40,7 @@ class ServeFns:
     init_params: Callable[[jax.Array], Any]
     init_cache: Callable[[], Any]
     prefill_chunk: Callable[..., Tuple[jnp.ndarray, jnp.ndarray, Any]]
-    decode: Callable[..., Tuple[jnp.ndarray, Any]]
+    decode: Callable[..., Tuple[jnp.ndarray, jnp.ndarray, Any]]
     reset_slots: Callable[[Any, jnp.ndarray], Any]
     param_shardings: Any = None
     cache_shardings: Any = None
@@ -134,8 +134,9 @@ def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
         logits, cache = model.decode_step(
             params, tokens[:, None], cache, lengths,
             valid=active[:, None])
-        nxt = sample(logits[:, -1], temperature=temperature)
-        return nxt, cache
+        last = logits[:, -1]                              # (B, V)
+        nxt = sample(last, temperature=temperature)
+        return nxt, last, cache
 
     reset = make_reset_slots(cfg)
 
@@ -150,7 +151,7 @@ def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
             _decode,
             in_shardings=(param_sh, cache_sh, scalar_sh, scalar_sh,
                           scalar_sh),
-            out_shardings=(scalar_sh, cache_sh),
+            out_shardings=(scalar_sh, None, cache_sh),
             donate_argnums=(1,) if donate else ())
         reset_fn = jax.jit(reset, in_shardings=(cache_sh, scalar_sh),
                            out_shardings=cache_sh,

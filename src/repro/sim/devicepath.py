@@ -37,6 +37,7 @@ behind an ``attn_impl``-style switch).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -533,6 +534,36 @@ def _materialize(spec, a: dict, fin_state, ys, r: int,
     )
 
 
+def _check_batch(specs) -> str:
+    """Validate a sweep batch; returns its shared scheduler."""
+    for spec in specs:
+        reason = device_eligible(spec)
+        if reason:
+            raise DevicePathError(
+                f"spec {spec.name!r} needs a host datapath: {reason}")
+    T = len(specs[0].tenants)
+    sched = specs[0].scheduler
+    for spec in specs:
+        if len(spec.tenants) != T or spec.scheduler != sched:
+            raise DevicePathError(
+                "sweep replicas must share tenant count and scheduler "
+                f"(got T={len(spec.tenants)}/{T}, "
+                f"scheduler={spec.scheduler!r}/{sched!r})")
+    return sched
+
+
+@contextlib.contextmanager
+def _precision(precision: str):
+    """Yield the lane float type; ``"exact"`` scopes ``enable_x64``."""
+    if precision == "exact":
+        with jax.enable_x64(True):
+            yield np.float64
+    elif precision == "fast":
+        yield np.float32
+    else:
+        raise ValueError(f"unknown precision {precision!r} (exact|fast)")
+
+
 def run_sweep_specs(specs: Sequence, *, impl: str = "",
                     precision: str = "exact",
                     record_completions: bool = False,
@@ -548,32 +579,35 @@ def run_sweep_specs(specs: Sequence, *, impl: str = "",
     """
     if not specs:
         return []
-    for spec in specs:
-        reason = device_eligible(spec)
-        if reason:
-            raise DevicePathError(
-                f"spec {spec.name!r} needs a host datapath: {reason}")
-    T = len(specs[0].tenants)
-    sched = specs[0].scheduler
-    for spec in specs:
-        if len(spec.tenants) != T or spec.scheduler != sched:
-            raise DevicePathError(
-                "sweep replicas must share tenant count and scheduler "
-                f"(got T={len(spec.tenants)}/{T}, "
-                f"scheduler={spec.scheduler!r}/{sched!r})")
-    if precision == "exact":
-        from jax.experimental import enable_x64
-        with enable_x64():
-            return _run_batch(list(specs), np.float64, sched, impl,
-                              record_completions)
-    if precision == "fast":
-        return _run_batch(list(specs), np.float32, sched, impl,
-                          record_completions)
-    raise ValueError(f"unknown precision {precision!r} (exact|fast)")
+    specs = list(specs)
+    sched = _check_batch(specs)
+    with _precision(precision) as ftype:
+        geom, state, data, per_spec = _prepare_batch(specs, ftype, sched,
+                                                     impl)
+        fin_state, eq = _build_launch(*geom)(state, data)
+        fin_state = jax.tree_util.tree_map(np.asarray, fin_state)
+        eq = jax.tree_util.tree_map(np.asarray, eq)
+    return [_materialize(s, per_spec[r], fin_state, eq, r,
+                         record_completions)
+            for r, s in enumerate(specs)]
 
 
-def _run_batch(specs, ftype, sched: str, impl: str,
-               record_completions: bool):
+def lower_sweep(specs: Sequence, *, impl: str = "",
+                precision: str = "exact"):
+    """``jax.stages.Lowered`` of the launch ``run_sweep_specs`` makes for
+    these specs: the same jitted function on the same arguments, so
+    ``.compile()`` here is the program that run executes (its text shows
+    whether the Pallas kernel is in it: ``tpu_custom_call``)."""
+    specs = list(specs)
+    sched = _check_batch(specs)
+    with _precision(precision) as ftype:
+        geom, state, data, _ = _prepare_batch(specs, ftype, sched, impl)
+        return _build_launch(*geom).lower(state, data)
+
+
+def _prepare_batch(specs, ftype, sched: str, impl: str):
+    """Host prep for one batch: the launch geometry (``_build_launch``'s
+    arguments), initial state, device data and replica arrays."""
     T = len(specs[0].tenants)
     P = PSPIN.num_pus
     per_spec = [_spec_arrays(s, ftype) for s in specs]
@@ -583,13 +617,63 @@ def _run_batch(specs, ftype, sched: str, impl: str,
     C = max(1, min(int(max(s.fifo_capacity for s in specs)), NB))
     S = 2 * max(a["n_live"] for a in per_spec) + 2
     state = _init_state(len(specs), T, P, C, NB, n_arr, ftype)
-    launch = _build_launch(T, P, C, S, sched, impl)
-    fin_state, eq = launch(state, data)
-    fin_state = jax.tree_util.tree_map(np.asarray, fin_state)
-    eq = jax.tree_util.tree_map(np.asarray, eq)
-    return [_materialize(s, per_spec[r], fin_state, eq, r,
-                         record_completions)
-            for r, s in enumerate(specs)]
+    return (T, P, C, S, sched, impl), state, data, per_spec
+
+
+# ---------------------------------------------------------------------------
+# the oracle: the same spec on the host batched datapath
+# ---------------------------------------------------------------------------
+PARITY_STAT_FIELDS = ("completed", "killed", "drops", "served_payload_bytes",
+                      "first_arrival", "last_completion",
+                      "kernel_time_count", "kernel_time_sum")
+
+
+def host_oracle(spec, *, record_completions: bool = True):
+    """The device path's reference: ``spec`` on the host batched
+    datapath (``sim/fastpath.py``, f64)."""
+    from repro.api.runtime import build_traces
+    from repro.core.slo import ECTX
+    from repro.sim.fastpath import build_simulator
+    tenants = [ECTX(tenant_id=i, name=t.name, slo=t.slo(),
+                    kernel=t.workload.build())
+               for i, t in enumerate(spec.tenants)]
+    sim = build_simulator(tenants, datapath="batched",
+                          scheduler=spec.scheduler, frag=spec.frag(),
+                          arb=spec.arbiter,
+                          fifo_capacity=spec.fifo_capacity,
+                          record_completions=record_completions)
+    ta = build_traces(spec, arrays=True)
+    horizon = spec.horizon_us * 1e3 if spec.horizon_us else None
+    return sim.run(ta, horizon=horizon)
+
+
+def parity_mismatches(spec, host, dev) -> List[str]:
+    """Names of the observables on which a device result ``dev`` is not
+    bit-identical to the ``host_oracle`` result (empty = exact parity).
+    The Jain time-average is the one documented drift (the host fold
+    compresses the active set before summing): it may differ by 1e-9."""
+    bad = []
+    if dev.time != host.time:
+        bad.append("time")
+    if dev.completions != host.completions:
+        bad.append("completions")
+    if ([(e.tenant, e.kind, e.time) for e in dev.events]
+            != [(e.tenant, e.kind, e.time) for e in host.events]):
+        bad.append("events")
+    for i in range(len(spec.tenants)):
+        hs, ds = host.stats[i], dev.stats[i]
+        for f in PARITY_STAT_FIELDS:
+            if getattr(ds, f) != getattr(hs, f):
+                bad.append(f"tenant{i}.{f}")
+        if ds.kernel_time_percentile(99) != hs.kernel_time_percentile(99):
+            bad.append(f"tenant{i}.p99_kernel_ns")
+    for k in ("prio", "total_occup", "bvt", "kv_pressure"):
+        if not np.array_equal(np.asarray(dev.sched_state[k]),
+                              np.asarray(host.sched_state[k])):
+            bad.append(f"sched_state.{k}")
+    if not abs(dev.jain_pu_timeavg - host.jain_pu_timeavg) <= 1e-9:
+        bad.append("jain_pu_timeavg")
+    return bad
 
 
 def run_device(spec, *, impl: str = "",

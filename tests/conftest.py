@@ -12,11 +12,11 @@ import pytest
 
 @pytest.fixture(scope="session")
 def host_mesh():
-    import jax
-    return jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((2, 4), ("data", "model"))
 
 
 @pytest.fixture(scope="session")
 def pod_mesh():
-    import jax
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((2, 2, 2), ("pod", "data", "model"))

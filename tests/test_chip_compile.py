@@ -1,0 +1,151 @@
+"""Compiles for a described TPU v5e chip (no chip attached).
+
+Each test lowers a program of the main device path against
+``ShapeDtypeStruct`` stand-ins placed on a device of a described
+``v5e:2x2`` topology and compiles it with the TPU compiler: what the chip's
+compiler refuses (unaligned Pallas slices, too much fast memory, 64-bit
+types, programs that do not fit HBM) fails here.  Nothing runs, so these
+say nothing about results or times.
+
+The topology is described inside a module fixture, never at import time:
+only one process may load the TPU library at once, and every test worker
+imports every test file.  Code that asks ``jax.default_backend()`` still
+sees the CPU here, so the tests steer that check with ``monkeypatch``.
+"""
+import dataclasses
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+V5E_HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler / library lock held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache off."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Make the repo's backend checks take their TPU branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _place(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+# ---------------------------------------------------------------------------
+# sweep surface
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("T", [8, 128])
+def test_rounds_pallas_compiles(one_chip, T):
+    from repro.kernels.wlbvt_select import _rounds_pallas
+    R = 256
+    f32 = jax.ShapeDtypeStruct((R, T), jnp.float32, sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((R, T), jnp.int32, sharding=one_chip)
+    fk = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=one_chip)
+    for max_picks in (1, 32):
+        fn = jax.jit(functools.partial(_rounds_pallas, num_pus=32,
+                                       max_picks=max_picks))
+        text = fn.lower(f32, i32, i32, f32, f32, fk).compile().as_text()
+        assert "tpu_custom_call" in text, max_picks
+
+
+def _fig9_batch(R, precision, impl):
+    from repro.api import get_scenario
+    from repro.sim import devicepath as DP
+    base = get_scenario("fig9_congestor_victim",
+                        duration_us=30.0).replace(record_timeline=False)
+    specs = [dataclasses.replace(base, seed=s) for s in range(R)]
+    with DP._precision(precision) as ftype:
+        geom, state, data, _ = DP._prepare_batch(specs, ftype, "wlbvt", impl)
+    # a fresh jit (not the lru-cached one): no trace from a CPU run reused
+    return DP._build_launch.__wrapped__(*geom), state, data
+
+
+def test_fig9_launch_compiles_with_pallas(one_chip, tpu_backend):
+    """The fast sweep launch, auto impl: the kernel is compiled in."""
+    launch, state, data = _fig9_batch(256, "fast", "")
+    compiled = launch.lower(_place(state, one_chip),
+                            _place(data, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fig9_exact_launch_compiles_without_kernel(one_chip, tpu_backend):
+    """f64 lanes, auto impl: the jnp select, through XLA's f64 emulation."""
+    with jax.enable_x64(True):
+        launch, state, data = _fig9_batch(8, "exact", "")
+        compiled = launch.lower(_place(state, one_chip),
+                                _place(data, one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_f64_pallas_raises(tpu_backend):
+    from repro.kernels.wlbvt_select import wlbvt_select_rounds
+    R, T = 8, 4
+    with jax.enable_x64(True):
+        f64 = np.ones((R, T), np.float64)
+        i32 = np.ones((R, T), np.int32)
+        with pytest.raises(ValueError, match="f32 lanes only"):
+            wlbvt_select_rounds(f64, i32, i32, f64, f64,
+                                np.ones(R, np.int32), num_pus=32,
+                                max_picks=1, impl="pallas")
+
+
+# ---------------------------------------------------------------------------
+# serving surface
+# ---------------------------------------------------------------------------
+def test_qwen3_decode_step_compiles(one_chip):
+    """One decode step of Qwen3-8B at published widths, 2 layers, bf16,
+    with the serving geometry of ``chip_smoke.py``."""
+    from repro.configs import get_config
+    from repro.serving.serve_step import build_serve_fns
+    cfg = dataclasses.replace(get_config("qwen3-8b"), num_layers=2,
+                              param_dtype="bfloat16")
+    B, L = 8, 2048
+    fns = build_serve_fns(cfg, None, batch=B, max_len=L, prefill_chunk=256)
+    params = jax.eval_shape(fns.model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(fns.model.init_cache, B, L))
+    i32 = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    act = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    compiled = fns.decode.lower(_place(params, one_chip),
+                                _place(cache, one_chip),
+                                i32, i32, act).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < V5E_HBM_BYTES
+    out = jax.eval_shape(fns.decode, params, cache, i32, i32, act)
+    assert out[1].shape == (B, cfg.vocab_size)

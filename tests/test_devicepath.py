@@ -16,48 +16,14 @@ from _prop import given, settings, st  # hypothesis or seeded fallback
 
 from repro.api import (ArrivalSpec, ScenarioSpec, SweepAxis, SweepSpec,
                        TenantSpec, WorkloadSpec, apply_knob, get_scenario)
-from repro.sim.devicepath import (DevicePathError, device_eligible,
-                                  run_device, run_sweep_specs)
-
-
-def _host_run(spec):
-    """The device's oracle: the same spec on the host batched datapath."""
-    from repro.api.runtime import build_traces
-    from repro.core.slo import ECTX
-    from repro.sim.fastpath import build_simulator
-    tenants = [ECTX(tenant_id=i, name=t.name, slo=t.slo(),
-                    kernel=t.workload.build())
-               for i, t in enumerate(spec.tenants)]
-    sim = build_simulator(tenants, datapath="batched",
-                          scheduler=spec.scheduler, frag=spec.frag(),
-                          arb=spec.arbiter,
-                          fifo_capacity=spec.fifo_capacity,
-                          record_completions=True)
-    ta = build_traces(spec, arrays=True)
-    horizon = spec.horizon_us * 1e3 if spec.horizon_us else None
-    return sim.run(ta, horizon=horizon)
-
-
-_STAT_FIELDS = ("completed", "killed", "drops", "served_payload_bytes",
-                "first_arrival", "last_completion", "kernel_time_count",
-                "kernel_time_sum")
+from repro.sim.devicepath import (PARITY_STAT_FIELDS, DevicePathError,
+                                  device_eligible, host_oracle,
+                                  parity_mismatches, run_device,
+                                  run_sweep_specs)
 
 
 def _assert_parity(spec, h, d):
-    assert d.time == h.time
-    assert d.completions == h.completions
-    assert ([(e.tenant, e.kind, e.time) for e in d.events]
-            == [(e.tenant, e.kind, e.time) for e in h.events])
-    for i in range(len(spec.tenants)):
-        hs, ds = h.stats[i], d.stats[i]
-        for f in _STAT_FIELDS:
-            assert getattr(ds, f) == getattr(hs, f), (i, f)
-        assert (ds.kernel_time_percentile(99)
-                == hs.kernel_time_percentile(99)), i
-    for k in ("prio", "total_occup", "bvt", "kv_pressure"):
-        np.testing.assert_array_equal(np.asarray(d.sched_state[k]),
-                                      np.asarray(h.sched_state[k]), k)
-    assert abs(d.jain_pu_timeavg - h.jain_pu_timeavg) <= 1e-9
+    assert parity_mismatches(spec, h, d) == []
 
 
 def _fig9(**kw):
@@ -81,7 +47,7 @@ def _fig9(**kw):
 ])
 def test_fig9_parity(leg, impl, kw):
     spec = _fig9(**kw)
-    _assert_parity(spec, _host_run(spec), run_device(spec, impl=impl))
+    _assert_parity(spec, host_oracle(spec), run_device(spec, impl=impl))
 
 
 def test_budget_kill_parity():
@@ -90,7 +56,7 @@ def test_budget_kill_parity():
                                     total_cycle_limit=20000)
                 for t in spec.tenants)
     spec = dataclasses.replace(spec, tenants=ten)
-    h, d = _host_run(spec), run_device(spec)
+    h, d = host_oracle(spec), run_device(spec)
     assert sum(s.killed for s in h.stats.values()) > 0  # kills exercised
     _assert_parity(spec, h, d)
 
@@ -105,7 +71,7 @@ def test_sweep_batch_matches_single_replica_runs():
         assert br.time == sr.time
         assert br.completions == sr.completions
         for i in range(len(spec.tenants)):
-            for f in _STAT_FIELDS:
+            for f in PARITY_STAT_FIELDS:
                 assert (getattr(br.stats[i], f)
                         == getattr(sr.stats[i], f)), (spec.seed, i, f)
 
@@ -147,13 +113,13 @@ def test_random_sweep_parity(data):
     specs = _mix(prios, slopes, limits, sched, seeds=(0, 1))
     device = run_sweep_specs(specs, record_completions=True)
     for spec, d in zip(specs, device):
-        h = _host_run(spec)
+        h = host_oracle(spec)
         assert d.time == h.time
         assert d.completions == h.completions
         assert ([(e.tenant, e.kind, e.time) for e in d.events]
                 == [(e.tenant, e.kind, e.time) for e in h.events])
         for i in range(T):
-            for f in _STAT_FIELDS:
+            for f in PARITY_STAT_FIELDS:
                 assert (getattr(d.stats[i], f)
                         == getattr(h.stats[i], f)), (spec.seed, i, f)
 
@@ -219,6 +185,27 @@ def test_select_rounds_rejects_oversize():
     with pytest.raises(ValueError):
         wlbvt_select_rounds(*args, num_pus=8, max_picks=1, impl="pallas",
                             interpret=True)
+
+
+@pytest.mark.parametrize("backend,dtype,pallas", [
+    ("tpu", np.float32, True),
+    ("tpu", np.float64, False),     # the TPU kernel compiler has no f64
+    ("cpu", np.float32, False),
+])
+def test_select_auto_impl(monkeypatch, backend, dtype, pallas):
+    """Auto impl takes the Pallas kernel only for f32 lanes on a TPU."""
+    import functools
+
+    import jax
+    from repro.kernels.wlbvt_select import wlbvt_select_rounds
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    prio, ql, co, to, bvt, free = _rand_round(np.random.RandomState(0),
+                                              R=8, T=4, num_pus=8)
+    fn = functools.partial(wlbvt_select_rounds, num_pus=8, max_picks=1)
+    with jax.enable_x64(dtype == np.float64):
+        f = [a.astype(dtype) for a in (prio, to, bvt)]
+        jaxpr = jax.make_jaxpr(fn)(f[0], ql, co, f[1], f[2], free)
+    assert ("pallas_call" in str(jaxpr)) == pallas
 
 
 # ---------------------------------------------------------------------------

@@ -5,10 +5,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed import collectives as C
-from repro.distributed.compat import shard_map
 from repro.distributed import compression as Q
 from repro.distributed import pipeline as PP
 
