@@ -184,6 +184,7 @@ def _rounds_pallas(prio, queue_len, cur_occup, total_occup, bvt, free_k, *,
             jax.ShapeDtypeStruct((Rp, _LANES), cur_occup.dtype),
         ],
         interpret=interpret,
+        name="wlbvt_select",
     )(prio_p, ql_p, co_p, to_p, bvt_p, free_p)
     return picks[:R, :max_picks], ql[:R, :T], co[:R, :T]
 

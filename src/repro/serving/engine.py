@@ -38,6 +38,7 @@ from repro.serving.kv_cache import SlotManager
 from repro.serving.request import Request, RequestStatus
 from repro.telemetry import G_IDX, GAUGES, tenant_report
 from repro.telemetry import trace as TR
+from repro.telemetry.wallclock import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,17 +98,24 @@ class ModelExecutor:
                        else self.fns.init_params(jax.random.PRNGKey(rng_seed)))
         self.cache = self.fns.init_cache()
 
+    def _call(self, dispatch: str, sync: str, fn, *host) -> np.ndarray:
+        """Enqueue ``fn`` on the device (inputs moved, the jitted call
+        returned), then wait for its next tokens on the host."""
+        with span(dispatch):
+            nxt, _, self.cache = fn(self.params, self.cache,
+                                    *map(self.jnp.asarray, host))
+        with span(sync):
+            return np.asarray(nxt)
+
     def prefill(self, tokens, lengths, valid_n):
-        nxt, _, self.cache = self.fns.prefill_chunk(
-            self.params, self.cache, self.jnp.asarray(tokens),
-            self.jnp.asarray(lengths), self.jnp.asarray(valid_n))
-        return np.asarray(nxt)
+        return self._call("osmosis.serve.prefill.dispatch",
+                          "osmosis.serve.prefill.sync",
+                          self.fns.prefill_chunk, tokens, lengths, valid_n)
 
     def decode(self, tokens, lengths, active):
-        nxt, _, self.cache = self.fns.decode(
-            self.params, self.cache, self.jnp.asarray(tokens),
-            self.jnp.asarray(lengths), self.jnp.asarray(active))
-        return np.asarray(nxt)
+        return self._call("osmosis.serve.decode.dispatch",
+                          "osmosis.serve.decode.sync",
+                          self.fns.decode, tokens, lengths, active)
 
     def reset(self, keep):
         self.cache = self.fns.reset_slots(self.cache,
@@ -351,21 +359,25 @@ class Engine(EngineBase):
                               TR.K_PU_WLBVT, cap=caps)
         return picks
 
-    def _assign_slots(self) -> None:
+    def _assign_slots(self) -> List[int]:
+        """Grant free slots (WLBVT round) and reset their cache rows;
+        returns the granted request ids."""
         k = int(self.slots.free_slots().size)
         if k == 0:
-            return
+            return []
         picks = self._select_round(k)
         if not picks:
-            return
+            return []
         keep = np.ones(self.cfg.max_slots, bool)
         tr = self.trace
+        granted = []
         for t in picks:
             req = self.queues[t].popleft()
             s = self.slots.take(t)
             req.slot = s
             req.status = RequestStatus.PREFILL
             req.start_step = self.step_count
+            granted.append(req.rid)
             self.slot_req[s] = req
             self.lengths[s] = 0
             keep[s] = False
@@ -377,6 +389,7 @@ class Engine(EngineBase):
         # invalidate stale cache rows for every slot assigned this step in
         # ONE batched call (R3 isolation, single XLA invocation)
         self.exe.reset(keep)
+        return granted
 
     def _finish(self, slot: int, status: RequestStatus,
                 kill_kind: EventKind = EventKind.REQUEST_KILLED) -> None:
@@ -407,9 +420,9 @@ class Engine(EngineBase):
             self.eq[t].push(Event(t, kill_kind, self.step_count,
                                   f"rid={req.rid}"))
 
-    def _prefill_phase(self) -> None:
-        """Chunked prefill with DWRR tenant arbitration (R2): at most
-        ``prefill_slots_per_step`` slots advance one fragment per step."""
+    def _prefill_arbitrate(self) -> List[int]:
+        """The slots whose next prompt fragment runs this step (DWRR
+        tenant arbitration, or oldest first under ``arbiter="fifo"``)."""
         C = self.cfg.prefill_chunk
         tr = self.trace
         pending_slots: Dict[int, List[int]] = {}
@@ -417,66 +430,75 @@ class Engine(EngineBase):
             if r is not None and r.status == RequestStatus.PREFILL:
                 pending_slots.setdefault(r.tenant_id, []).append(s)
         if not pending_slots:
-            return
-        chosen: List[int] = []
+            return []
         if self.cfg.arbiter == "fifo":
             # no-QoS baseline: oldest requests first regardless of tenant
             order = sorted(
                 (s for ss in pending_slots.values() for s in ss),
                 key=lambda s: self.slot_req[s].rid)
-            chosen = order[: self.cfg.prefill_slots_per_step]
-        else:
-            T = self.cfg.max_tenants
-            counts = np.zeros(T, np.int64)
-            for i, ss in pending_slots.items():
-                counts[i] = len(ss)
-            head = np.full(T, float(C))
-            d0 = self.dwrr.deficit.copy() if tr is not None else None
-            c0 = counts.copy() if tr is not None else None
-            picks = W.dwrr_select_k(self.dwrr, head, counts,
-                                    quantum=float(C),
-                                    k=self.cfg.prefill_slots_per_step)
-            if tr is not None:
-                TR.record_dwrr_round(
-                    tr, float(self.step_count), TR.K_AXI_DWRR,
-                    [int(i) for i in picks if i >= 0], d0, c0,
-                    self.dwrr.weights)
-            chosen = [pending_slots[int(i)].pop(0) for i in picks if i >= 0]
+            return order[: self.cfg.prefill_slots_per_step]
+        T = self.cfg.max_tenants
+        counts = np.zeros(T, np.int64)
+        for i, ss in pending_slots.items():
+            counts[i] = len(ss)
+        head = np.full(T, float(C))
+        d0 = self.dwrr.deficit.copy() if tr is not None else None
+        c0 = counts.copy() if tr is not None else None
+        picks = W.dwrr_select_k(self.dwrr, head, counts,
+                                quantum=float(C),
+                                k=self.cfg.prefill_slots_per_step)
+        if tr is not None:
+            TR.record_dwrr_round(
+                tr, float(self.step_count), TR.K_AXI_DWRR,
+                [int(i) for i in picks if i >= 0], d0, c0,
+                self.dwrr.weights)
+        return [pending_slots[int(i)].pop(0) for i in picks if i >= 0]
 
-        if not chosen:
-            return
-        B = self.cfg.max_slots
-        tokens = np.zeros((B, C), np.int32)
-        valid_n = np.zeros(B, np.int32)
-        for s in chosen:
-            r = self.slot_req[s]
-            n = min(C, r.prompt_len - r.prefill_done)
-            tokens[s, :n] = r.prompt[r.prefill_done:r.prefill_done + n]
-            valid_n[s] = n
+    def _prefill_phase(self) -> None:
+        """Chunked prefill with DWRR tenant arbitration (R2): at most
+        ``prefill_slots_per_step`` slots advance one fragment per step."""
+        C = self.cfg.prefill_chunk
+        tr = self.trace
+        with span("osmosis.serve.prefill.pack", step=self.step_count) as sp:
+            chosen = self._prefill_arbitrate()
+            if not chosen:
+                return
+            if sp.is_enabled():
+                sp.set_metadata(rids=[self.slot_req[s].rid for s in chosen])
+            B = self.cfg.max_slots
+            tokens = np.zeros((B, C), np.int32)
+            valid_n = np.zeros(B, np.int32)
+            for s in chosen:
+                r = self.slot_req[s]
+                n = min(C, r.prompt_len - r.prefill_done)
+                tokens[s, :n] = r.prompt[r.prefill_done:r.prefill_done + n]
+                valid_n[s] = n
         nxt = self.exe.prefill(tokens, self.lengths.copy(), valid_n)
         self.prefill_chunks += 1
-        for s in chosen:
-            r = self.slot_req[s]
-            n = int(valid_n[s])
-            r.prefill_done += n
-            self.lengths[s] += n
-            self._charge_tokens(r.tenant_id, n)
-            r.chunk_steps.append(self.step_count)
-            if tr is not None:
-                # chunked prefill is the DMA-fragmentation analog: one
-                # zero-width DMA marker per fragment (step clock has no
-                # intra-step duration, so PU+FMQ still reconcile exactly)
-                uid = self._tr_uid_by_rid.get(r.rid, -1)
-                now = float(self.step_count)
-                tr.span(TR.ST_DMA, uid, r.tenant_id, now, now, TR.D_OK,
-                        pu=s)
-            if r.prefill_done >= r.prompt_len:
-                r.status = RequestStatus.DECODE
-                r.generated.append(int(nxt[s]))
-                self.last_tok[s] = nxt[s]
-            if self._over_total_budget(r.tenant_id):
-                self._finish(s, RequestStatus.KILLED,
-                             kill_kind=EventKind.TOTAL_BUDGET_EXCEEDED)
+        with span("osmosis.serve.tokens", step=self.step_count):
+            for s in chosen:
+                r = self.slot_req[s]
+                n = int(valid_n[s])
+                r.prefill_done += n
+                self.lengths[s] += n
+                self._charge_tokens(r.tenant_id, n)
+                r.chunk_steps.append(self.step_count)
+                if tr is not None:
+                    # chunked prefill is the DMA-fragmentation analog: one
+                    # zero-width DMA marker per fragment (step clock has no
+                    # intra-step duration, so PU+FMQ still reconcile
+                    # exactly)
+                    uid = self._tr_uid_by_rid.get(r.rid, -1)
+                    now = float(self.step_count)
+                    tr.span(TR.ST_DMA, uid, r.tenant_id, now, now, TR.D_OK,
+                            pu=s)
+                if r.prefill_done >= r.prompt_len:
+                    r.status = RequestStatus.DECODE
+                    r.generated.append(int(nxt[s]))
+                    self.last_tok[s] = nxt[s]
+                if self._over_total_budget(r.tenant_id):
+                    self._finish(s, RequestStatus.KILLED,
+                                 kill_kind=EventKind.TOTAL_BUDGET_EXCEEDED)
 
     def _decode_phase(self) -> None:
         active = np.array([
@@ -487,20 +509,21 @@ class Engine(EngineBase):
         nxt = self.exe.decode(self.last_tok.copy(), self.lengths.copy(),
                               active)
         self.decode_steps += 1
-        for s in np.flatnonzero(active):
-            r = self.slot_req[s]
-            self.lengths[s] += 1
-            r.generated.append(int(nxt[s]))
-            self.last_tok[s] = nxt[s]
-            self._charge_tokens(r.tenant_id, 1)
-            limit = self.ectx[r.tenant_id].slo.kernel_cycle_limit
-            if self._over_total_budget(r.tenant_id):
-                self._finish(s, RequestStatus.KILLED,
-                             kill_kind=EventKind.TOTAL_BUDGET_EXCEEDED)
-            elif limit and r.total_tokens > limit:
-                self._finish(s, RequestStatus.KILLED)
-            elif len(r.generated) >= r.max_new_tokens:
-                self._finish(s, RequestStatus.DONE)
+        with span("osmosis.serve.tokens", step=self.step_count):
+            for s in np.flatnonzero(active):
+                r = self.slot_req[s]
+                self.lengths[s] += 1
+                r.generated.append(int(nxt[s]))
+                self.last_tok[s] = nxt[s]
+                self._charge_tokens(r.tenant_id, 1)
+                limit = self.ectx[r.tenant_id].slo.kernel_cycle_limit
+                if self._over_total_budget(r.tenant_id):
+                    self._finish(s, RequestStatus.KILLED,
+                                 kill_kind=EventKind.TOTAL_BUDGET_EXCEEDED)
+                elif limit and r.total_tokens > limit:
+                    self._finish(s, RequestStatus.KILLED)
+                elif len(r.generated) >= r.max_new_tokens:
+                    self._finish(s, RequestStatus.DONE)
 
     def _charge_tokens(self, tenant: int, n: int) -> None:
         self.budget.charge(tenant, n)
@@ -549,23 +572,28 @@ class Engine(EngineBase):
                 t=float(self.step_count))
 
     def step(self) -> None:
-        # R5: control traffic first
-        while self._control:
-            self._control.popleft()()
-        self._assign_slots()
+        k = self.step_count
+        with span("osmosis.serve.admit", step=k) as sp:
+            # R5: control traffic first
+            while self._control:
+                self._control.popleft()()
+            granted = self._assign_slots()
+            if granted and sp.is_enabled():
+                sp.set_metadata(rids=granted)
         self._prefill_phase()
         self._decode_phase()
-        # WLBVT accounting + fairness (per engine step = one "cycle")
-        W.advance(self.st, 1.0)
-        act = self.st.active & self._installed
-        if act.sum() >= 2:
-            self.fairness.update(
-                self.st.cur_occup[act], 1.0,
-                weights=self.st.prio[act])
-        if self.tel is not None:
-            self._commit_telemetry()
-        if self.trace is not None:
-            self.trace.maybe_commit()
+        with span("osmosis.serve.account", step=k):
+            # WLBVT accounting + fairness (per engine step = one "cycle")
+            W.advance(self.st, 1.0)
+            act = self.st.active & self._installed
+            if act.sum() >= 2:
+                self.fairness.update(
+                    self.st.cur_occup[act], 1.0,
+                    weights=self.st.prio[act])
+            if self.tel is not None:
+                self._commit_telemetry()
+            if self.trace is not None:
+                self.trace.maybe_commit()
         self.step_count += 1
 
     def run(self, steps: int) -> None:

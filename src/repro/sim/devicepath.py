@@ -51,6 +51,7 @@ from repro.configs.osmosis_pspin import PSPIN
 from repro.core import sched_generic as G
 from repro.core.events import Event, EventKind
 from repro.kernels.wlbvt_select import wlbvt_select_rounds
+from repro.telemetry.wallclock import span
 
 EQ_RING_CAPACITY = 4096   # host EQHub shared-queue retention
 
@@ -581,15 +582,19 @@ def run_sweep_specs(specs: Sequence, *, impl: str = "",
         return []
     specs = list(specs)
     sched = _check_batch(specs)
+    R = len(specs)
     with _precision(precision) as ftype:
         geom, state, data, per_spec = _prepare_batch(specs, ftype, sched,
                                                      impl)
-        fin_state, eq = _build_launch(*geom)(state, data)
-        fin_state = jax.tree_util.tree_map(np.asarray, fin_state)
-        eq = jax.tree_util.tree_map(np.asarray, eq)
-    return [_materialize(s, per_spec[r], fin_state, eq, r,
-                         record_completions)
-            for r, s in enumerate(specs)]
+        with span("osmosis.sweep.launch", replicas=R):
+            fin_state, eq = _build_launch(*geom)(state, data)
+        with span("osmosis.sweep.fetch", replicas=R):
+            fin_state = jax.tree_util.tree_map(np.asarray, fin_state)
+            eq = jax.tree_util.tree_map(np.asarray, eq)
+    with span("osmosis.sweep.materialize", replicas=R):
+        return [_materialize(s, per_spec[r], fin_state, eq, r,
+                             record_completions)
+                for r, s in enumerate(specs)]
 
 
 def lower_sweep(specs: Sequence, *, impl: str = "",
@@ -610,13 +615,16 @@ def _prepare_batch(specs, ftype, sched: str, impl: str):
     arguments), initial state, device data and replica arrays."""
     T = len(specs[0].tenants)
     P = PSPIN.num_pus
-    per_spec = [_spec_arrays(s, ftype) for s in specs]
-    data, n_arr, NB = _stack_data(per_spec, ftype)
-    if NB >= (1 << 30) - 1:   # slot meta packs pkt | kill<<30 | bk<<31
-        raise DevicePathError(f"trace too long for device path ({NB})")
-    C = max(1, min(int(max(s.fifo_capacity for s in specs)), NB))
-    S = 2 * max(a["n_live"] for a in per_spec) + 2
-    state = _init_state(len(specs), T, P, C, NB, n_arr, ftype)
+    R = len(specs)
+    with span("osmosis.sweep.build", replicas=R):
+        per_spec = [_spec_arrays(s, ftype) for s in specs]
+    with span("osmosis.sweep.stack", replicas=R):
+        data, n_arr, NB = _stack_data(per_spec, ftype)
+        if NB >= (1 << 30) - 1:   # slot meta packs pkt | kill<<30 | bk<<31
+            raise DevicePathError(f"trace too long for device path ({NB})")
+        C = max(1, min(int(max(s.fifo_capacity for s in specs)), NB))
+        S = 2 * max(a["n_live"] for a in per_spec) + 2
+        state = _init_state(R, T, P, C, NB, n_arr, ftype)
     return (T, P, C, S, sched, impl), state, data, per_spec
 
 
