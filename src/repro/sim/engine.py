@@ -88,8 +88,10 @@ class TenantStats:
         """Bulk replay of ``record_kernel_time`` over ``values`` in
         order, bit-identical to the sequential calls: the fill phase is
         a copy, the sum a ``cumsum`` tail (left-to-right accumulation,
-        same rounding as ``+=``), and only samples past the reservoir
-        cap walk the replacement rng one draw at a time."""
+        same rounding as ``+=``), and the samples past the reservoir cap
+        take their slots from one array draw of the replacement rng,
+        which yields the scalar draws' values and final state; where
+        several samples hit one slot, the last one wins, as in order."""
         values = np.asarray(values, dtype=float)
         if values.size == 0:
             return
@@ -103,10 +105,12 @@ class TenantStats:
         self._kt_buf = buf
         if values.size > KT_RESERVOIR_CAP:
             rng = np.random.default_rng(_KT_RNG_SEED)
-            for k in range(KT_RESERVOIR_CAP, values.size):
-                j = int(rng.integers(0, k + 1))
-                if j < KT_RESERVOIR_CAP:
-                    buf[j] = values[k]
+            ks = np.arange(KT_RESERVOIR_CAP, values.size)
+            js = rng.integers(0, ks + 1)
+            hit = js < KT_RESERVOIR_CAP
+            ks, js = ks[hit][::-1], js[hit][::-1]
+            slots, last = np.unique(js, return_index=True)
+            buf[slots] = values[ks[last]]
             self._kt_rng = rng
         self.kernel_time_count = int(values.size)
         self.kernel_time_sum = float(values.cumsum()[-1])

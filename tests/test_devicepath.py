@@ -50,6 +50,22 @@ def test_fig9_parity(leg, impl, kw):
     _assert_parity(spec, host_oracle(spec), run_device(spec, impl=impl))
 
 
+def test_fig9_parity_past_reservoir_cap():
+    """Long enough that every tenant's kernel-time reservoir passes its
+    cap, so ``_materialize`` runs the bulk Algorithm-R replay; the
+    reservoirs and their percentiles must match the host oracle exactly."""
+    from repro.sim.engine import KT_RESERVOIR_CAP
+    spec = _fig9(duration_us=130.0)
+    h, d = host_oracle(spec), run_device(spec, impl="jnp",
+                                         precision="exact")
+    assert all(s.kernel_time_count > KT_RESERVOIR_CAP
+               for s in d.stats.values())
+    _assert_parity(spec, h, d)
+    for i in range(len(spec.tenants)):
+        assert np.array_equal(d.stats[i].kernel_times,
+                              h.stats[i].kernel_times)
+
+
 def test_budget_kill_parity():
     spec = _fig9()
     ten = tuple(dataclasses.replace(t, kernel_cycle_limit=300,

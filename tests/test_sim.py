@@ -217,6 +217,36 @@ def test_kernel_time_reservoir_bounded_and_exact_below_cap():
     assert np.array_equal(st.kernel_times, st2.kernel_times)
 
 
+@pytest.mark.parametrize("extra", [-1, 0, 1, "3cap+517"])
+def test_kernel_time_bulk_replay_matches_sequential(extra):
+    """``record_kernel_times`` (one array draw past the cap) leaves the
+    reservoir, count, sum, percentiles and rng state exactly as the
+    per-value ``record_kernel_time`` calls do, and the stream continues
+    identically afterwards."""
+    from repro.sim.engine import KT_RESERVOIR_CAP, TenantStats
+    n = (3 * KT_RESERVOIR_CAP + 517 if extra == "3cap+517"
+         else KT_RESERVOIR_CAP + extra)
+    vals = np.random.default_rng(11).uniform(10.0, 1000.0, size=n)
+    bulk, seq = TenantStats(), TenantStats()
+    bulk.record_kernel_times(vals)
+    for v in vals:
+        seq.record_kernel_time(float(v))
+    assert np.array_equal(bulk.kernel_times, seq.kernel_times)
+    assert bulk.kernel_time_count == seq.kernel_time_count == n
+    assert bulk.kernel_time_sum == seq.kernel_time_sum
+    for q in (50, 99):
+        assert (bulk.kernel_time_percentile(q)
+                == seq.kernel_time_percentile(q))
+    if n > KT_RESERVOIR_CAP:
+        assert (bulk._kt_rng.bit_generator.state
+                == seq._kt_rng.bit_generator.state)
+    else:
+        assert bulk._kt_rng is None and seq._kt_rng is None
+    for st in (bulk, seq):
+        st.record_kernel_time(12345.0)
+    assert np.array_equal(bulk.kernel_times, seq.kernel_times)
+
+
 def test_sim_kernel_times_bounded_end_to_end():
     """A long congested run keeps per-tenant kernel-time memory at the
     reservoir cap while p50/p99 stay exact running-count-aware."""
