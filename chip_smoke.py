@@ -239,6 +239,10 @@ def serve_requests(cfg, seed: int, mesh=None, phase: str = "serve"):
     t0 = time.perf_counter()
     exe.fns.prefill_chunk.lower(exe.params, exe.cache,
                                 np.zeros((B, C), np.int32), zi, zi).compile()
+    zp = np.zeros(exe.width, np.int32)      # what the engine's chunks run
+    exe.fns.prefill_rows.lower(exe.params, exe.cache,
+                               np.zeros((exe.width, C), np.int32), zp, zp,
+                               zp).compile()
     exe.fns.decode.lower(exe.params, exe.cache, zi, zi,
                          np.zeros(B, bool)).compile()
     exe.fns.reset_slots.lower(exe.cache, np.zeros(B, bool)).compile()

@@ -82,7 +82,12 @@ class NullExecutor:
 
 
 class ModelExecutor:
-    """Real data plane: jitted prefill/decode/reset over a Model."""
+    """Real data plane: jitted prefill/decode/reset over a Model.
+
+    A prefill call runs over the rows it advances: the program is
+    ``width`` = min(``prefill_slots_per_step``, ``max_slots``) rows wide,
+    the most the engine's arbitration advances a step, and a call with
+    fewer rows is padded with slots that stay unchanged."""
 
     def __init__(self, model_cfg: ModelConfig, ecfg: EngineConfig,
                  params=None, mesh=None, rng_seed: int = 0,
@@ -91,26 +96,49 @@ class ModelExecutor:
         import jax.numpy as jnp
         from repro.serving.serve_step import build_serve_fns
         self.jnp = jnp
+        self.width = min(ecfg.prefill_slots_per_step, ecfg.max_slots)
         self.fns = build_serve_fns(
             model_cfg, mesh, batch=ecfg.max_slots, max_len=ecfg.max_len,
-            prefill_chunk=ecfg.prefill_chunk, temperature=temperature)
+            prefill_chunk=ecfg.prefill_chunk, prefill_width=self.width,
+            temperature=temperature)
         self.params = (params if params is not None
                        else self.fns.init_params(jax.random.PRNGKey(rng_seed)))
         self.cache = self.fns.init_cache()
 
-    def _call(self, dispatch: str, sync: str, fn, *host) -> np.ndarray:
+    def _call(self, dispatch: str, sync: str, fn, *host, **stats):
         """Enqueue ``fn`` on the device (inputs moved, the jitted call
-        returned), then wait for its next tokens on the host."""
-        with span(dispatch):
+        returned), then wait for its next tokens on the host.  ``stats``
+        go on the dispatch span while a profiler records."""
+        with span(dispatch) as sp:
+            if stats and sp.is_enabled():
+                sp.set_metadata(**stats)
             nxt, _, self.cache = fn(self.params, self.cache,
                                     *map(self.jnp.asarray, host))
         with span(sync):
             return np.asarray(nxt)
 
     def prefill(self, tokens, lengths, valid_n):
-        return self._call("osmosis.serve.prefill.dispatch",
-                          "osmosis.serve.prefill.sync",
-                          self.fns.prefill_chunk, tokens, lengths, valid_n)
+        """Next tokens (B,) of one prefill chunk over the slots with
+        ``valid_n > 0``, at most ``width`` of them; those of the other
+        slots mean nothing."""
+        B, P = len(valid_n), self.width
+        rows = np.flatnonzero(valid_n > 0)
+        if rows.size > P:
+            raise ValueError(f"{rows.size} slots to prefill; the program "
+                             f"advances at most {P}")
+        # padded with distinct other slots, which the program writes back
+        # as they were
+        slots = np.concatenate(
+            [rows, np.setdiff1d(np.arange(B), rows)[:P - rows.size]])
+        n = np.zeros(P, np.int32)
+        n[:rows.size] = valid_n[rows]
+        out = self._call("osmosis.serve.prefill.dispatch",
+                         "osmosis.serve.prefill.sync", self.fns.prefill_rows,
+                         tokens[slots], lengths[slots], n,
+                         slots.astype(np.int32), rows=rows.size, width=P)
+        nxt = np.zeros(B, np.int32)
+        nxt[rows] = out[:rows.size]
+        return nxt
 
     def decode(self, tokens, lengths, active):
         return self._call("osmosis.serve.decode.dispatch",
@@ -137,6 +165,13 @@ class Engine(EngineBase):
                          trace_pus=ecfg.max_slots)
         self.cfg = ecfg
         self.exe = executor or NullExecutor(ecfg)
+        width = getattr(self.exe, "width", None)    # a prefill program's rows
+        if width is not None and width != min(ecfg.prefill_slots_per_step,
+                                              ecfg.max_slots):
+            raise ValueError(
+                f"the executor's prefill program is {width} rows wide; the "
+                f"engine advances up to {ecfg.prefill_slots_per_step} slots "
+                f"of {ecfg.max_slots} a step")
         self.ectx = self.ectxs          # legacy aliases for the public
         self.eq = self.eqhub.queues     # surface (dict views, shared state)
         self.tokens_used = self.budget.spent

@@ -7,6 +7,13 @@ programs the engine (and the dry-run) calls:
       -> (next_token (B,), last_logits (B,V), cache)
     Ragged tails are exact: pad entries are written with position -1 and
     recurrent state is untouched past valid_n (see models' ``valid`` path).
+    The dry-run lowers it; the engine's executor runs ``prefill_rows``.
+  * ``prefill_rows(params, cache, tokens(P,C), lengths(P,), valid_n(P,),
+    slots(P,))`` -> (next_token (P,), last_logits (P,V), cache), P =
+    ``prefill_width``: the same step over the cache rows ``slots`` only,
+    gathered, prefilled and written back inside the one program; a row
+    with ``valid_n == 0`` goes back unchanged, and no other slot's row is
+    touched.  ``slots`` must be distinct.
   * ``decode(params, cache, tokens(B,), lengths(B,), active(B,))``
       -> (next_token (B,), logits (B,V), cache)
   * ``reset_slots(cache, keep_mask(B,))`` — zero/invalidate freed slots'
@@ -42,6 +49,7 @@ class ServeFns:
     prefill_chunk: Callable[..., Tuple[jnp.ndarray, jnp.ndarray, Any]]
     decode: Callable[..., Tuple[jnp.ndarray, jnp.ndarray, Any]]
     reset_slots: Callable[[Any, jnp.ndarray], Any]
+    prefill_rows: Callable[..., Tuple[jnp.ndarray, jnp.ndarray, Any]]
     param_shardings: Any = None
     cache_shardings: Any = None
 
@@ -70,13 +78,40 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
+def _batch_leaf(path, x) -> int:
+    return max(_cache_batch_dim(_path_str(path), x.ndim), 0)
+
+
+def _take_rows(cache, slots):
+    """The rows ``slots`` (P,) of every cache leaf, along its batch dim."""
+    def leaf(path, x):
+        b = _batch_leaf(path, x)
+        return jnp.concatenate(
+            [jax.lax.dynamic_slice_in_dim(x, slots[i], 1, axis=b)
+             for i in range(slots.shape[0])], axis=b)
+    return jax.tree_util.tree_map_with_path(leaf, cache)
+
+
+def _put_rows(cache, slots, rows):
+    """``cache`` with the rows ``slots`` (P,) of every leaf set to ``rows``
+    (one in-place update per row)."""
+    def leaf(path, x, r):
+        b = _batch_leaf(path, x)
+        for i in range(slots.shape[0]):
+            x = jax.lax.dynamic_update_slice_in_dim(
+                x, jax.lax.slice_in_dim(r, i, i + 1, axis=b), slots[i],
+                axis=b)
+        return x
+    return jax.tree_util.tree_map_with_path(leaf, cache, rows)
+
+
 def make_reset_slots(cfg: ModelConfig):
     """reset(cache, keep (B,) bool) -> cache with dropped slots invalidated."""
 
     def reset(cache, keep):
         def leaf(path, x):
             p = _path_str(path)
-            bdim = max(_cache_batch_dim(p, x.ndim), 0)
+            bdim = _batch_leaf(path, x)
             shape = [1] * x.ndim
             shape[bdim] = x.shape[bdim]
             k = keep.reshape(shape)
@@ -93,16 +128,20 @@ def make_reset_slots(cfg: ModelConfig):
 
 def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
                     batch: int, max_len: int, prefill_chunk: int = 256,
+                    prefill_width: Optional[int] = None,
                     moe_impl: str = "gshard", temperature: float = 0.0,
                     donate: bool = True, shard_cache_length: bool = False
                     ) -> ServeFns:
+    """``prefill_width``: the rows of ``prefill_rows`` (at most
+    ``batch``, which None means)."""
     model = build_model(cfg, moe_impl=moe_impl)
+    width = min(prefill_width or batch, batch)
     if cfg.window_size:
         prefill_chunk = min(prefill_chunk, cfg.window_size)
     SH.set_activation_mesh(mesh)   # in-scan activation anchors
 
     # ---- shardings ---------------------------------------------------------
-    param_sh = cache_sh = tok_sh = scalar_sh = None
+    param_sh = cache_sh = tok_sh = scalar_sh = row_sh = sub_sh = None
     if mesh is not None:
         params_sds = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         pspecs = SH.param_pspecs(cfg, params_sds, mesh, "serve")
@@ -117,18 +156,47 @@ def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
         bspec = SH.batch_pspec(mesh, batch)
         tok_sh = NamedSharding(mesh, bspec)
         scalar_sh = NamedSharding(mesh, bspec)
+        # the gathered rows keep the cache's placement (heads, or the
+        # position leaf's length, on ``model``)
+        sub_sds = jax.eval_shape(
+            functools.partial(model.init_cache, width, max_len))
+        sub_sh = jax.tree.map(
+            lambda s: NamedSharding(mesh, s),
+            SH.cache_pspecs(cfg, sub_sds, mesh,
+                            shard_length=shard_cache_length),
+            is_leaf=lambda x: isinstance(x, P))
+        row_sh = NamedSharding(mesh, SH.batch_pspec(mesh, width))
 
     # ---- step bodies ---------------------------------------------------------
-    def _prefill(params, cache, tokens, lengths, valid_n):
+    def _rows(tree):
+        if sub_sh is None:
+            return tree
+        return jax.lax.with_sharding_constraint(tree, sub_sh)
+
+    def _prefill(params, cache, tokens, lengths, valid_n, slots=None):
+        """One prefill chunk over every slot (``slots`` None), or over the
+        cache rows ``slots`` alone (see the module docstring)."""
+        full = cache
+        if slots is not None:
+            cache = _rows(_take_rows(full, slots))
         B, C = tokens.shape
         valid = jnp.arange(C)[None, :] < valid_n[:, None]
-        logits, cache = model.prefill(params, tokens, cache, lengths,
-                                      valid=valid)
+        logits, new = model.prefill(params, tokens, cache, lengths,
+                                    valid=valid)
         last = jnp.take_along_axis(
             logits, jnp.maximum(valid_n - 1, 0)[:, None, None], axis=1
         )[:, 0]                                           # (B, V)
         nxt = sample(last, temperature=temperature)
-        return nxt, last, cache
+        if slots is None:
+            return nxt, last, new
+        adv = valid_n > 0
+
+        def keep(path, n, o):
+            b = _batch_leaf(path, n)
+            shape = (1,) * b + (B,) + (1,) * (n.ndim - b - 1)
+            return jnp.where(adv.reshape(shape), n, o)
+        new = _rows(jax.tree_util.tree_map_with_path(keep, new, cache))
+        return nxt, last, _put_rows(full, slots, new)
 
     def _decode(params, cache, tokens, lengths, active):
         logits, cache = model.decode_step(
@@ -141,11 +209,18 @@ def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
     reset = make_reset_slots(cfg)
 
     # ---- jit ----------------------------------------------------------------
+    # both prefill programs jit ``_prefill``, so each is ``jit__prefill`` on
+    # a device trace
     if mesh is not None:
         prefill_fn = jax.jit(
             _prefill,
             in_shardings=(param_sh, cache_sh, tok_sh, scalar_sh, scalar_sh),
             out_shardings=(scalar_sh, None, cache_sh),
+            donate_argnums=(1,) if donate else ())
+        rows_fn = jax.jit(
+            _prefill,
+            in_shardings=(param_sh, cache_sh) + (row_sh,) * 4,
+            out_shardings=(row_sh, None, cache_sh),
             donate_argnums=(1,) if donate else ())
         decode_fn = jax.jit(
             _decode,
@@ -162,6 +237,7 @@ def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
             out_shardings=cache_sh)
     else:
         prefill_fn = jax.jit(_prefill, donate_argnums=(1,) if donate else ())
+        rows_fn = jax.jit(_prefill, donate_argnums=(1,) if donate else ())
         decode_fn = jax.jit(_decode, donate_argnums=(1,) if donate else ())
         reset_fn = jax.jit(reset, donate_argnums=(0,) if donate else ())
         init_params = jax.jit(model.init)
@@ -171,4 +247,5 @@ def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
     return ServeFns(cfg=cfg, model=model, init_params=init_params,
                     init_cache=init_cache, prefill_chunk=prefill_fn,
                     decode=decode_fn, reset_slots=reset_fn,
-                    param_shardings=param_sh, cache_shardings=cache_sh)
+                    param_shardings=param_sh, cache_shardings=cache_sh,
+                    prefill_rows=rows_fn)

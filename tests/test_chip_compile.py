@@ -149,3 +149,49 @@ def test_qwen3_decode_step_compiles(one_chip):
     assert used < V5E_HBM_BYTES
     out = jax.eval_shape(fns.decode, params, cache, i32, i32, act)
     assert out[1].shape == (B, cfg.vocab_size)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_qwen3_prefill_rows_compiles(topo, chips):
+    """The serving prefill program two rows wide (``prefill_rows``) at
+    Qwen3-8B's published widths, 2 layers, bf16, 8 slots x 2048, on one
+    chip and tensor parallel on ``(data=1, model=4)``: its layer loop and
+    its all-reduces carry the 2 rows alone, and the cache is updated in
+    place."""
+    import re
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
+    from repro.serving.serve_step import build_serve_fns
+    cfg = dataclasses.replace(get_config("qwen3-8b"), num_layers=2,
+                              param_dtype="bfloat16", tie_embeddings=False)
+    B, L, C = 8, 2048, 256
+    mesh = (make_mesh((1, 4), ("data", "model"), devices=topo.devices)
+            if chips == 4 else None)
+    fns = build_serve_fns(cfg, mesh, batch=B, max_len=L, prefill_chunk=C,
+                          prefill_width=2)
+    params = jax.eval_shape(fns.model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(fns.model.init_cache, B, L))
+    if mesh is None:
+        one = SingleDeviceSharding(topo.devices[0])
+        params, cache = _place(params, one), _place(cache, one)
+        row = one
+    else:
+        params, cache = (
+            jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=s), t, sh)
+            for t, sh in ((params, fns.param_shardings),
+                          (cache, fns.cache_shardings)))
+        row = NamedSharding(mesh, PartitionSpec())
+    toks = jax.ShapeDtypeStruct((2, C), jnp.int32, sharding=row)
+    i32 = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=row)
+    compiled = fns.prefill_rows.lower(params, cache, toks, i32, i32,
+                                      i32).compile()
+    text = compiled.as_text()
+    assert "bf16[2,256,4096]" in text and "bf16[8,256,4096]" not in text
+    if chips == 4:
+        assert set(re.findall(r"(\w+\[[\d,]+\])\S* all-reduce\(", text)) \
+            == {"bf16[2,256,4096]"}
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache)) // chips
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes

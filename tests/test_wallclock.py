@@ -130,6 +130,21 @@ def test_engine_spans_nest_in_their_step(exe, tmp_path):
     assert granted == {r.rid for r in eng.done}
 
 
+def test_prefill_dispatch_carries_rows_and_width(exe, tmp_path):
+    """Each prefill call's dispatch span counts the rows it advances (the
+    requests its chunk packed) against the program's width."""
+    _serve(exe)                                  # compile outside the trace
+    with profiled(tmp_path) as spans:
+        eng = _serve(exe)
+    disp = [s[3] for s in spans if s[0] == "osmosis.serve.prefill.dispatch"]
+    packs = [s[3]["rids"] for s in spans
+             if s[0] == "osmosis.serve.prefill.pack" and "rids" in s[3]]
+    assert len(disp) == len(packs) == eng.prefill_chunks
+    assert {int(d["width"]) for d in disp} == {ECFG.prefill_slots_per_step}
+    assert [int(d["rows"]) for d in disp] == [
+        len(str(r).strip("[]").split(",")) for r in packs]
+
+
 class _NoSpan(contextlib.nullcontext):
     """What the engine's call sites see with the spans taken out."""
 
