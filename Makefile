@@ -1,7 +1,7 @@
 PY ?= python
 export PYTHONPATH := src
 
-.PHONY: lint analysis baseline test test-fast bench
+.PHONY: lint analysis baseline test test-fast
 
 # repo-aware static checkers (jit-purity, time-unit flow, EQ-event
 # exhaustiveness, frozen-spec/fixed-shape) + ruff/mypy when installed
@@ -21,6 +21,3 @@ test:
 
 test-fast:
 	$(PY) -m pytest -x -q -m "not slow"
-
-bench:
-	$(PY) -m benchmarks.run

@@ -7,7 +7,7 @@ present — the CI gate.
 
 Options::
 
-    paths...          roots to scan (default: src benchmarks examples tests)
+    paths...          roots to scan (default: src examples tests)
     --root DIR        repo root (default: auto-detect from cwd upward)
     --baseline FILE   baseline path (default: <root>/analysis_baseline.json)
     --json            machine-readable report on stdout

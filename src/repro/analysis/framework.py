@@ -27,7 +27,7 @@ SEVERITIES = ("error", "warning")
 DEFAULT_EXCLUDES = (
     "tests/data/*", "*/.git/*", "*/__pycache__/*", "build/*", "dist/*",
 )
-DEFAULT_PATHS = ("src", "benchmarks", "examples", "tests")
+DEFAULT_PATHS = ("src", "examples", "tests")
 
 
 # ---------------------------------------------------------------------------

@@ -81,8 +81,7 @@ class FrozenSpecRule(Rule):
     description = ("ScenarioSpec/TenantSpec and friends are immutable "
                    "after construction — use spec.replace(...)")
 
-    def __init__(self, scope: Tuple[str, ...] = ("src/*", "benchmarks/*",
-                                                 "examples/*"),
+    def __init__(self, scope: Tuple[str, ...] = ("src/*", "examples/*"),
                  defining: Tuple[str, ...] = DEFINING_MODULES):
         self.scope = scope
         self.defining = defining

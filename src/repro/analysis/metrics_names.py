@@ -71,8 +71,7 @@ class MetricNamesRule(Rule):
                    "whitelist), counters must end _total, and no "
                    "name+labelset may repeat")
 
-    def __init__(self, scope: Tuple[str, ...] = ("src/*", "benchmarks/*",
-                                                 "examples/*")):
+    def __init__(self, scope: Tuple[str, ...] = ("src/*", "examples/*")):
         self.scope = scope
 
     def run(self, index: RepoIndex) -> List[Finding]:

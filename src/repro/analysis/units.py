@@ -299,8 +299,7 @@ class TimeUnitFlowRule(Rule):
                    "without explicit conversion; RunReport time_unit "
                    "literals must come from TIME_UNITS")
 
-    def __init__(self, scope: Tuple[str, ...] = ("src/*", "benchmarks/*",
-                                                 "examples/*")):
+    def __init__(self, scope: Tuple[str, ...] = ("src/*", "examples/*")):
         self.scope = scope
 
     def run(self, index: RepoIndex) -> List[Finding]:

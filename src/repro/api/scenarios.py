@@ -1,7 +1,7 @@
 """Built-in scenario catalog: every scenario formerly hand-coded in
 ``sim/scenarios.py`` plus the serving-native scenarios formerly inlined
-in ``launch/serve.py``, ``benchmarks/serving_fairness.py`` and the
-examples — all as registered declarative ``ScenarioSpec`` factories.
+in ``launch/serve.py`` and the examples — all as registered declarative
+``ScenarioSpec`` factories.
 
 Run any of them by name:
 

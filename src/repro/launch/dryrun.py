@@ -3,7 +3,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # ^ MUST precede any jax import: jax locks the device count on first init.
 # The 512 host devices exist only for this dry-run driver; tests and
-# benchmarks see the real single CPU device.  This tool compiles on
+# other tools see the real single CPU device.  This tool compiles on
 # virtual CPU devices only, so on a machine with a TPU it never takes it.
 """Multi-pod dry-run: .lower().compile() every (arch x shape x mesh) cell.
 
@@ -18,8 +18,8 @@ decode shapes) against ShapeDtypeStruct stand-ins on the production mesh
     every all-gather / all-reduce / reduce-scatter / all-to-all /
     collective-permute, per primitive
 
-Records are JSON files under benchmarks/results/dryrun/ consumed by
-benchmarks/roofline.py.  Usage:
+Each record is printed; the exit code is non-zero when a cell fails to
+lower or compile.  Usage:
 
     python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k
     python -m repro.launch.dryrun --all             # 40 cells x 2 meshes
@@ -27,15 +27,11 @@ benchmarks/roofline.py.  Usage:
 """
 import argparse
 import dataclasses
-import json
 import re
 import subprocess
 import sys
 import time
 from typing import Dict, Optional
-
-RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                           "benchmarks", "results", "dryrun")
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -200,10 +196,9 @@ def lower_prefill(cfg, shape, mesh, moe_impl: str):
 # cell driver
 # ---------------------------------------------------------------------------
 def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
-             moe_impl: str = "gshard", save: bool = True,
+             moe_impl: str = "gshard",
              attn_impl: Optional[str] = None,
-             seq_parallel: bool = False,
-             tag: str = "") -> Dict:
+             seq_parallel: bool = False) -> Dict:
     import jax
     from repro.configs import SHAPES, cell_supported, get_config, param_count
     from repro.launch.mesh import make_production_mesh
@@ -214,13 +209,11 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
     shape = SHAPES[shape_name]
     mesh_name = "multipod" if multi_pod else "singlepod"
     rec: Dict = {"arch": arch, "shape": shape_name, "mesh": mesh_name,
-                 "kind": shape.kind, "moe_impl": moe_impl, "tag": tag,
+                 "kind": shape.kind, "moe_impl": moe_impl,
                  "params": param_count(cfg)}
     ok, reason = cell_supported(cfg, shape)
     if not ok:
         rec["skipped"] = reason
-        if save:
-            _save(rec, tag)
         return rec
 
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -257,7 +250,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
     # trip-count-aware walk of the post-SPMD HLO (launch/hlo_stats.py):
-    # the numbers the roofline actually uses
+    # the numbers the record reports
     from repro.launch.hlo_stats import analyze as hlo_analyze
     hs = hlo_analyze(compiled.as_text())
     rec["cost"] = {"flops": hs["flops"], "bytes_accessed": hs["bytes"]}
@@ -267,17 +260,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
                      "all-to-all", "collective-permute")},
         "total_bytes": hs["collective_bytes"],
     }
-    if save:
-        _save(rec, tag)
     return rec
-
-
-def _save(rec: Dict, tag: str = "") -> None:
-    os.makedirs(RESULTS_DIR, exist_ok=True)
-    suffix = f"__{tag}" if tag else ""
-    fname = f"{rec['arch']}__{rec['shape']}__{rec['mesh']}{suffix}.json"
-    with open(os.path.join(RESULTS_DIR, fname), "w") as f:
-        json.dump(rec, f, indent=1)
 
 
 def _print_rec(rec: Dict) -> None:
@@ -312,7 +295,6 @@ def main(argv=None) -> int:
     ap.add_argument("--attn-impl", default=None)
     ap.add_argument("--seq-parallel", action="store_true",
                     help="sequence-parallel residual stream (train cells)")
-    ap.add_argument("--tag", default="")
     ap.add_argument("--subprocess-per-cell", action="store_true",
                     help="isolate each cell in a fresh process (RAM hygiene)")
     args = ap.parse_args(argv)
@@ -339,15 +321,13 @@ def main(argv=None) -> int:
                     cmd += ["--attn-impl", args.attn_impl]
                 if args.seq_parallel:
                     cmd += ["--seq-parallel"]
-                if args.tag:
-                    cmd += ["--tag", args.tag]
                 r = subprocess.run(cmd)
                 failures += (r.returncode != 0)
                 continue
             try:
                 rec = run_cell(arch, shape, mp, moe_impl=args.moe_impl,
                                attn_impl=args.attn_impl,
-                               seq_parallel=args.seq_parallel, tag=args.tag)
+                               seq_parallel=args.seq_parallel)
                 _print_rec(rec)
             except Exception as e:  # noqa: BLE001
                 failures += 1

@@ -21,11 +21,6 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.distributed import sharding as SH
 
-# v5e hardware constants used by the roofline analysis (benchmarks/roofline.py)
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link
-
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
               devices=None) -> Mesh:

@@ -7,7 +7,7 @@ the portable RunReport (DESIGN.md §7).
     PYTHONPATH=src python -m repro.launch.scenario qos_closed_loop \
         --backend serve
     PYTHONPATH=src python -m repro.launch.scenario --all --fast \
-        --out-dir benchmarks/results/run_reports
+        --out-dir /tmp/run_reports
 
 Scenario parameters are overridable with ``--set key=value`` (repeat as
 needed); values parse as JSON where possible (``--set scheduler=rr``,

@@ -20,7 +20,7 @@ Two consumption surfaces:
     time; the run's wall clock pays exactly what the sink costs.
 
 With nothing attached the engines' per-interval cost is one attribute
-check (see ``benchmarks/export_overhead.py`` for the gate).
+check.
 """
 from __future__ import annotations
 

@@ -129,7 +129,8 @@ def test_f64_pallas_raises(tpu_backend):
 # ---------------------------------------------------------------------------
 def test_qwen3_decode_step_compiles(one_chip):
     """One decode step of Qwen3-8B at published widths, 2 layers, bf16,
-    with the serving geometry of ``chip_smoke.py``."""
+    with the serving geometry of the benchmark's serve cells (8 slots x
+    2048 tokens, 256-token prefill chunks)."""
     from repro.configs import get_config
     from repro.serving.serve_step import build_serve_fns
     cfg = dataclasses.replace(get_config("qwen3-8b"), num_layers=2,

@@ -34,6 +34,18 @@ def _fig9(**kw):
     return dataclasses.replace(spec, record_timeline=False, **kw)
 
 
+# An 8-tenant heterogeneous mix: cost slope, packet size and priority
+# differ per tenant, so no two scheduler lanes look alike.
+_MIX8 = tuple(
+    TenantSpec(f"t{i}",
+               workload=WorkloadSpec(name=f"w{i}", compute_base=40.0,
+                                     compute_per_byte=0.3 + 0.05 * (i % 7)),
+               arrival=ArrivalSpec(size=256 + 64 * (i % 5), share=1.0 / 8,
+                                   seed_offset=i),
+               priority=1.0 + (i % 3))
+    for i in range(8))
+
+
 # ---------------------------------------------------------------------------
 # golden parity: device == host batched, bit for bit
 # ---------------------------------------------------------------------------
@@ -44,6 +56,7 @@ def _fig9(**kw):
     ("rr", "jnp", {"scheduler": "rr"}),
     ("fifo8", "jnp", {"fifo_capacity": 8}),
     ("horizon", "jnp", {"duration_us": 40.0, "horizon_us": 20.0}),
+    ("mix8", "jnp", {"tenants": _MIX8, "duration_us": 20.0}),
 ])
 def test_fig9_parity(leg, impl, kw):
     spec = _fig9(**kw)

@@ -64,14 +64,50 @@ def test_simulator_decisions_match_seed(golden, gen):
                 want[scenario][key], rel=1e-9), (scenario, key)
 
 
-@pytest.mark.parametrize("datapath", ["event", "batched"])
-def test_sim_datapath_golden(golden, gen, datapath):
+def _flood32(datapath):
+    """Per-tenant completed/killed/drops/last_completion and the EQ event
+    count of a 400 us flood of the 400 Gb/s link at T = 32: one victim
+    and 31 spin congestors that burn their 50k-cycle watchdog budget."""
+    from repro.core import ECTX, SLOPolicy
+    from repro.sim.fastpath import build_simulator
+    from repro.sim.traffic import equal_share_traces
+    from repro.sim.workloads import spin_workload
+    T, duration_ns = 32, 400e3
+    tenants = []
+    for i in range(T):
+        wl, limit = ((spin_workload("victim", 0.6), 0) if i == 0 else
+                     (spin_workload(f"congestor{i}", 200.0), 50000))
+        tenants.append(ECTX(
+            tenant_id=i, name=wl.name,
+            slo=SLOPolicy(priority=1.0, kernel_cycle_limit=limit),
+            kernel=wl))
+    sim = build_simulator(tenants, datapath=datapath, fifo_capacity=256)
+    trace = equal_share_traces(T, sizes=[512] * T, duration_ns=duration_ns,
+                               arrays=True)
+    res = sim.run(trace if datapath == "batched" else trace.to_packets(),
+                  horizon=duration_ns)
+    return {"tenants": [[res.stats[i].completed, res.stats[i].killed,
+                         res.stats[i].drops, res.stats[i].last_completion]
+                        for i in range(T)],
+            "n_events": len(res.events)}
+
+
+@pytest.mark.parametrize("datapath,flood", [
+    pytest.param("event", "golden", id="event"),
+    pytest.param("batched", "golden", id="batched"),
+    pytest.param("batched", "t32", id="batched-flood32"),
+])
+def test_sim_datapath_golden(golden, gen, datapath, flood):
     """Both simulator datapaths reproduce the golden per-packet
     completion times and EQ event sequence (incl. the ring's eviction
     behavior at capacity) bit-for-bit on a drop/mark/kill-heavy flood
-    (DESIGN.md §8)."""
-    got = json.loads(json.dumps(gen.sim_datapath_trace(datapath)))
-    assert got == golden["sim_datapath"]
+    (DESIGN.md §8).  At T = 32 no golden is stored: the event loop's own
+    run is the reference for the batched path."""
+    if flood == "golden":
+        got = json.loads(json.dumps(gen.sim_datapath_trace(datapath)))
+        assert got == golden["sim_datapath"]
+    else:
+        assert _flood32(datapath) == _flood32("event")
 
 
 def _run_report(spec, datapath):

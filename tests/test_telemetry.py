@@ -6,6 +6,8 @@ behavior, both engines' event wiring (ECN, lifetime budget,
 backpressure), the closed-loop congestor-vs-victim acceptance demo, and
 the <3% recording-overhead budget.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -438,12 +440,67 @@ def test_tenant_report_structure():
     json.dumps(rep)                               # JSON-able
 
 
+def _overhead_engine():
+    """A smoke-model engine with telemetry on (numpy backend) and a
+    standing backlog that keeps it busy through the measurement."""
+    from repro.configs import smoke_config
+    from repro.serving.engine import Engine, EngineConfig, ModelExecutor
+    from repro.serving.request import Request
+    ecfg = EngineConfig(max_slots=8, max_len=128, prefill_chunk=32,
+                        max_tenants=16, kv_overcommit=4.0, telemetry=True)
+    eng = Engine(ecfg, executor=ModelExecutor(smoke_config("qwen3-8b"),
+                                              ecfg, rng_seed=0))
+    rng = np.random.RandomState(0)
+    for t in range(4):
+        eng.create_ectx(t, SLOPolicy(kv_quota_tokens=128 * 2))
+    for i in range(64):
+        eng.submit(Request(i % 4, rng.randint(1, 90, 16).astype(np.int32),
+                           max_new_tokens=24))
+    return eng
+
+
+def _time_steps(eng, steps: int, warmup: int = 8) -> float:
+    """Mean seconds per engine step after warmup."""
+    for _ in range(warmup):
+        eng.step()
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        eng.step()
+    return (time.perf_counter() - t0) / steps
+
+
+def _stage_typical(tel) -> None:
+    """A representative per-step event load: a few arrivals, token
+    charges, and two request completions."""
+    for t in range(4):
+        tel.inc("arrivals", t)
+        tel.inc("tokens", t, 8.0)
+    tel.lat(0, 12.0)
+    tel.lat(1, 30.0)
+
+
+def _time_commit(eng, iters: int = 300) -> float:
+    """Mean seconds per full telemetry commit (stage + flush + window)."""
+    for _ in range(8):
+        _stage_typical(eng.tel)
+        eng._commit_telemetry()
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        _stage_typical(eng.tel)
+        eng._commit_telemetry()
+    return (time.perf_counter() - t0) / iters
+
+
 def test_recording_overhead_within_budget():
     """Acceptance: telemetry recording costs <3% of a model-backed
-    engine step (measured directly; see benchmarks/telemetry_overhead)."""
-    from benchmarks.telemetry_overhead import BUDGET_PCT, measure
-    step_s, commit_np, _ = measure(use_model=True, steps=24)
-    assert 100.0 * commit_np / step_s < BUDGET_PCT
+    engine step.  The commit path (``Engine._commit_telemetry``) is timed
+    directly against the steady-state step: its cost (tens of us) is far
+    below run-to-run step-time noise, so differencing runs with and
+    without telemetry would measure the noise."""
+    eng = _overhead_engine()
+    step_s = _time_steps(eng, steps=24)
+    assert 100.0 * _time_commit(eng) / step_s < 3.0
+
 
 def test_format_console_labels_time_columns():
     from repro.telemetry import format_console
