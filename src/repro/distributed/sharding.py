@@ -319,13 +319,19 @@ def cache_pspecs(cfg: ModelConfig, cache: Any, mesh: Mesh,
     divisible, else the *length* dim over ``model`` (GQA kv=8 on a 16-way
     TP axis — e.g. qwen3/llama4 decode — shards the 32k context instead).
     ``shard_length`` (long_500k, batch=1): length over ``data`` too.
+    The positions (``pos``) take the length placement of their layer's
+    keys and values: whole on each ``model`` shard when the heads are split
+    there, so the in-place writes and the masks built from them need no
+    exchange.
 
-    Cache leaves are (B,T,H,D) k/v/xk/xv, (B,T,r) ckv/krope, (B,T) pos,
+    Cache leaves are (B,H,T,D) k/v/xk/xv, (B,T,r) ckv/krope, (B,T) pos,
     (B,W-1,C) conv, (B,H,P,N) ssd state, (B,W) rglru h — possibly under
     leading scan-stack dims; the trailing structure is keyed by leaf name.
     """
     data = "data" if _axis_size(mesh, "data") > 1 else None
     model = "model" if _axis_size(mesh, "model") > 1 else None
+    heads_on_model = (model is not None and cfg.mla is None
+                      and cfg.num_kv_heads % _axis_size(mesh, model) == 0)
 
     def leaf(path, x):
         p = _path_str(path)
@@ -347,10 +353,10 @@ def cache_pspecs(cfg: ModelConfig, cache: Any, mesh: Mesh,
             tdim = nd - 2
         elif last == "h":
             bdim = nd - 2
-        else:  # k / v / xk / xv
+        else:  # k / v / xk / xv, head-major
             bdim = nd - 4
-            tdim = nd - 3
-            hdim = nd - 2
+            hdim = nd - 3
+            tdim = nd - 2
         bdim = max(bdim, 0)
 
         def fits(dim, axis):
@@ -363,7 +369,8 @@ def cache_pspecs(cfg: ModelConfig, cache: Any, mesh: Mesh,
             spec[tdim] = data
         if fits(hdim, model):
             spec[hdim] = model
-        elif tdim is not None and spec[tdim] is None and fits(tdim, model):
+        elif (tdim is not None and spec[tdim] is None and fits(tdim, model)
+              and not (last == "pos" and heads_on_model)):
             spec[tdim] = model
         return P(*spec)
 

@@ -1,6 +1,7 @@
 """Public jit'd wrappers around the Pallas TPU kernels.
 
-Layout adapters fold model-layout tensors ((B,S,H,D) etc.) into the
+Layout adapters fold model-layout tensors (queries (B,S,H,D), keys and
+values head-major (B,H,T,D), as the KV cache holds them) into the
 kernel-native folded layouts, dispatch to pl.pallas_call, and restore the
 model layout.  ``interpret=True`` (automatic on CPU via ``on_cpu()``) runs
 the kernel bodies in the Pallas interpreter — the correctness path used by
@@ -30,16 +31,16 @@ def on_cpu() -> bool:
 def flash_attention(q, k, v, *, scale: float, causal: bool = True,
                     window: int = 0, cap: float = 0.0, bq: int = 128,
                     bk: int = 128, interpret: bool = False) -> jnp.ndarray:
-    """q: (B,S,Hq,D); k/v: (B,T,Hkv,D) -> (B,S,Hq,D).  GQA folded: query
+    """q: (B,S,Hq,D); k/v: (B,Hkv,T,D) -> (B,S,Hq,D).  GQA folded: query
     heads of one KV head become extra query rows (position-major)."""
     B, S, Hq, D = q.shape
-    T, Hkv = k.shape[1], k.shape[2]
+    Hkv, T = k.shape[1], k.shape[2]
     G = Hq // Hkv
     qf = (q.reshape(B, S, Hkv, G, D)
           .transpose(0, 2, 1, 3, 4)          # (B,Hkv,S,G,D)
           .reshape(B * Hkv, S * G, D))
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D)
+    kf = k.reshape(B * Hkv, T, D)
+    vf = v.reshape(B * Hkv, T, D)
     out = flash_attention_folded(qf, kf, vf, groups=G, scale=scale,
                                  causal=causal, window=window, cap=cap,
                                  bq=bq, bk=bk, interpret=interpret)
@@ -51,13 +52,14 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
 def decode_attention(q, k, v, lengths, *, scale: float, window: int = 0,
                      cap: float = 0.0, bk: int = 512,
                      interpret: bool = False) -> jnp.ndarray:
-    """q: (B,1,Hq,D); k/v: (B,T,Hkv,D); lengths: (B,) -> (B,1,Hq,D)."""
+    """q: (B,1,Hq,D); k/v: (B,Hkv,T,D), the KV cache's layout; lengths:
+    (B,) -> (B,1,Hq,D)."""
     B, _, Hq, D = q.shape
-    T, Hkv = k.shape[1], k.shape[2]
+    Hkv, T = k.shape[1], k.shape[2]
     G = Hq // Hkv
     qf = q.reshape(B, Hkv, G, D).reshape(B * Hkv, G, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D)
+    kf = k.reshape(B * Hkv, T, D)
+    vf = v.reshape(B * Hkv, T, D)
     lens = jnp.repeat(lengths.astype(jnp.int32), Hkv)
     out = decode_attention_folded(qf, kf, vf, lens, scale=scale,
                                   window=window, cap=cap, bk=bk,

@@ -7,9 +7,11 @@ Three implementations selected by ``cfg.attn_impl``:
     kernels/decode_attention.py); validated in interpret mode on CPU.
   * ``naive``   — full S×T score matrix; tiny-shape oracle only.
 
-Local-attention layers use a ring-buffer KV cache of ``window`` entries with
-stored absolute positions, so gemma2/recurrentgemma long-context decode
-memory is O(window), not O(context).
+Keys and values reach the attention functions head-major, (B, H_kv, T, D):
+the layout the KV cache stores, so decode reads a layer's cache as it lies.
+Every cache is a ring of ``T`` entries with stored absolute positions;
+local-attention layers keep ``window`` entries, so gemma2/recurrentgemma
+long-context decode memory is O(window), not O(context).
 """
 from __future__ import annotations
 
@@ -82,8 +84,10 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
                       causal: bool = True, window: int = 0,
                       cap: float = 0.0, chunk: int = 512,
                       k_valid=None, seg_q=None, seg_k=None,
-                      q_chunk: int = 4096) -> jnp.ndarray:
-    """Flash-style attention, pure jnp.  q: (B,S,Hq,Dk); k/v: (B,T,H,D*).
+                      q_chunk: int = 4096, layer=None) -> jnp.ndarray:
+    """Flash-style attention, pure jnp.  q: (B,S,Hq,Dk); k/v: (B,H,T,D*),
+    or with ``layer`` a layer stack (G,B,H,T,D*) of which entry ``layer``
+    is read, chunk by chunk, where it lies.
 
     Long sequences are processed in ``q_chunk`` query blocks (unrolled,
     static shapes).  For the causal self-attention layout (T == S, no
@@ -94,7 +98,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
     online-softmax accumulator (O(S·D) memory).
     """
     B, S, Hq, Dk = q.shape
-    T = k.shape[1]
+    T = k.shape[-2]
     if S > q_chunk and S % q_chunk == 0 and q_pos.ndim == 2:
         outs = []
         for i in range(S // q_chunk):
@@ -105,33 +109,45 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
                 hi = (i + 1) * q_chunk
                 lo = max(0, i * q_chunk - window + 1) if window else 0
                 lo = (lo // chunk) * chunk          # chunk-aligned band
-                ki, vi, kpi = k[:, lo:hi], v[:, lo:hi], k_pos[:, lo:hi]
+                ki, vi, kpi = k[:, :, lo:hi], v[:, :, lo:hi], k_pos[:, lo:hi]
                 ski = seg_k[:, lo:hi] if seg_k is not None else None
             else:
                 ki, vi, kpi, ski = k, v, k_pos, seg_k
             outs.append(_chunked_attention(
                 qi, ki, vi, qpi, kpi, scale=scale, causal=causal,
                 window=window, cap=cap, chunk=chunk, k_valid=k_valid,
-                seg_q=sqi, seg_k=ski))
+                seg_q=sqi, seg_k=ski, layer=layer))
         return jnp.concatenate(outs, axis=1)
     return _chunked_attention(q, k, v, q_pos, k_pos, scale=scale,
                               causal=causal, window=window, cap=cap,
                               chunk=chunk, k_valid=k_valid, seg_q=seg_q,
-                              seg_k=seg_k)
+                              seg_k=seg_k, layer=layer)
+
+
+def _kv_chunk(x, i, c: int, layer=None):
+    """Entries [i c, (i + 1) c) of keys or values (B,H,T,D), or of entry
+    ``layer`` of a stack (G,B,H,T,D): a slice XLA fuses into its reader."""
+    if layer is None:
+        return jax.lax.dynamic_slice_in_dim(x, i * c, c, axis=2)
+    return jax.lax.dynamic_slice(
+        x, (layer, 0, 0, i * c, 0), (1,) + x.shape[1:3] + (c,) + x.shape[4:]
+    )[0]
 
 
 def _chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
                        causal: bool = True, window: int = 0,
                        cap: float = 0.0, chunk: int = 512,
-                       k_valid=None, seg_q=None, seg_k=None) -> jnp.ndarray:
-    """q: (B,S,Hq,Dk), k: (B,T,Hkv,Dk), v: (B,T,Hkv,Dv).
+                       k_valid=None, seg_q=None, seg_k=None,
+                       layer=None) -> jnp.ndarray:
+    """q: (B,S,Hq,Dk), k: (B,Hkv,T,Dk), v: (B,Hkv,T,Dv) (entry ``layer``
+    of a stack of them, see ``chunked_attention``).
 
     q_pos: (B,S) absolute positions of queries; k_pos: (B,T) of keys.
     k_valid: (B,T) bool — entries that exist (cache fill mask).
     Returns (B,S,Hq,Dv).  All accumulation in fp32.
     """
     B, S, Hq, Dk = q.shape
-    T, Hkv = k.shape[1], k.shape[2]
+    Hkv, T = k.shape[-3], k.shape[-2]
     Dv = v.shape[-1]
     G = Hq // Hkv
 
@@ -139,8 +155,8 @@ def _chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
     n_chunks = -(-T // c)
     pad = n_chunks * c - T
     if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        widths = [(0, 0)] * (k.ndim - 2) + [(0, pad), (0, 0)]
+        k, v = jnp.pad(k, widths), jnp.pad(v, widths)
         k_pos = jnp.pad(k_pos, ((0, 0), (0, pad)), constant_values=-1)
         kv_mask = jnp.pad(
             jnp.ones((B, T), bool) if k_valid is None else k_valid,
@@ -151,8 +167,6 @@ def _chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
         kv_mask = jnp.ones((B, T), bool) if k_valid is None else k_valid
 
     qf = (q * jnp.asarray(scale, q.dtype)).reshape(B, S, Hkv, G, Dk)
-    kc = k.reshape(B, n_chunks, c, Hkv, Dk)
-    vc = v.reshape(B, n_chunks, c, Hkv, Dv)
     pc = k_pos.reshape(B, n_chunks, c)
     mc = kv_mask.reshape(B, n_chunks, c)
     sc = seg_k.reshape(B, n_chunks, c) if seg_k is not None else None
@@ -160,12 +174,15 @@ def _chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
     def body(carry, xs):
         m_run, l_run, acc = carry
         if sc is None:
-            k_i, v_i, p_i, valid_i = xs
+            i, p_i, valid_i = xs
             s_i = None
         else:
-            k_i, v_i, p_i, valid_i, s_i = xs
+            i, p_i, valid_i, s_i = xs
+        # the chunk's keys and values are read where they lie (a KV cache
+        # in place), not restacked chunk-major
+        k_i, v_i = _kv_chunk(k, i, c, layer), _kv_chunk(v, i, c, layer)
         # scores: (B, S, Hkv, G, c) — bf16 operands, fp32 MXU accumulation
-        s = jnp.einsum("bshgd,bchd->bshgc", qf, k_i,
+        s = jnp.einsum("bshgd,bhcd->bshgc", qf, k_i,
                        preferred_element_type=jnp.float32)
         if cap:
             s = cap * jnp.tanh(s / cap)
@@ -182,16 +199,16 @@ def _chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
         p = jnp.exp(s - m_new[..., None]) * mask       # masked probs
         corr = jnp.exp(m_run - m_new)
         l_new = l_run * corr + jnp.sum(p, axis=-1)
+        # v first: the CPU backend's bf16 dot runs in this operand order
         acc = acc * corr[..., None] + jnp.einsum(
-            "bshgc,bchd->bshgd", p.astype(v_i.dtype), v_i,
+            "bhcd,bshgc->bshgd", v_i, p.astype(v_i.dtype),
             preferred_element_type=jnp.float32)
         return (m_new, l_new, acc), None
 
     m0 = jnp.full((B, S, Hkv, G), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, S, Hkv, G), jnp.float32)
     a0 = jnp.zeros((B, S, Hkv, G, Dv), jnp.float32)
-    xs = (kc.swapaxes(0, 1), vc.swapaxes(0, 1), pc.swapaxes(0, 1),
-          mc.swapaxes(0, 1))
+    xs = (jnp.arange(n_chunks), pc.swapaxes(0, 1), mc.swapaxes(0, 1))
     if sc is not None:
         xs = xs + (sc.swapaxes(0, 1),)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), xs)
@@ -202,6 +219,7 @@ def _chunked_attention(q, k, v, q_pos, k_pos, *, scale: float,
 def naive_attention(q, k, v, q_pos, k_pos, *, scale, causal=True, window=0,
                     cap=0.0, k_valid=None, seg_q=None, seg_k=None):
     """Full-score attention (decode path + tiny-shape oracle).
+    q: (B,S,Hq,Dk); k/v: (B,Hkv,T,D*), the cache's own layout.
 
     No fp32 materialization of k/v: the MXU consumes bf16 operands and
     accumulates fp32 (``preferred_element_type``) — casting the KV cache
@@ -210,14 +228,14 @@ def naive_attention(q, k, v, q_pos, k_pos, *, scale, causal=True, window=0,
     the PV matmul (standard TPU flash practice; exact when v is fp32).
     """
     B, S, Hq, Dk = q.shape
-    Hkv = k.shape[2]
+    Hkv, T = k.shape[1], k.shape[2]
     G = Hq // Hkv
     qf = (q * jnp.asarray(scale, q.dtype)).reshape(B, S, Hkv, G, Dk)
-    s = jnp.einsum("bshgd,bthd->bshgt", qf, k,
+    s = jnp.einsum("bshgd,bhtd->bshgt", qf, k,
                    preferred_element_type=jnp.float32)
     if cap:
         s = cap * jnp.tanh(s / cap)
-    mask = jnp.ones((B, S, k.shape[1]), bool)
+    mask = jnp.ones((B, S, T), bool)
     if k_valid is not None:
         mask = mask & k_valid[:, None, :]
     if causal:
@@ -229,44 +247,54 @@ def naive_attention(q, k, v, q_pos, k_pos, *, scale, causal=True, window=0,
     s = jnp.where(mask[:, :, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     p = p * mask[:, :, None, None, :]
-    out = jnp.einsum("bshgt,bthd->bshgd", p.astype(v.dtype), v,
+    # v first: the CPU backend's bf16 dot runs in this operand order
+    out = jnp.einsum("bhtd,bshgt->bshgd", v, p.astype(v.dtype),
                      preferred_element_type=jnp.float32)
     return out.reshape(B, S, Hq, -1).astype(q.dtype)
 
 
 def _run_attention(cfg: ModelConfig, q, k, v, q_pos, k_pos, *, scale, causal,
-                   window, cap, k_valid=None, seg_q=None, seg_k=None):
-    if cfg.attn_impl == "pallas":
+                   window, cap, k_valid=None, seg_q=None, seg_k=None,
+                   layer=None):
+    """k/v: (B,Hkv,T,D), or with ``layer`` a layer stack (G,B,Hkv,T,D) of
+    which entry ``layer`` is attended: the chunked scan reads it in place,
+    the one-query paths through a slice XLA fuses into their dots."""
+    decode = q.shape[1] == 1
+    kernel = (cfg.attn_impl == "pallas" and seg_q is None
+              and k.shape[-1] == v.shape[-1]
+              and (decode or (k_valid is None and q.shape[1] == k.shape[-2])))
+    if not (kernel or cfg.attn_impl == "naive" or decode):
+        return chunked_attention(q, k, v, q_pos, k_pos, scale=scale,
+                                 causal=causal, window=window, cap=cap,
+                                 chunk=cfg.attn_chunk, k_valid=k_valid,
+                                 seg_q=seg_q, seg_k=seg_k, layer=layer)
+    if layer is not None:
+        k, v = (jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+                for x in (k, v))
+    if kernel:
         from repro.kernels import ops as kops
-        if seg_q is None and k.shape[-1] == v.shape[-1]:
-            if q.shape[1] == 1:   # decode
-                return kops.decode_attention(
-                    q, k, v, q_pos[:, 0], scale=scale, window=window,
-                    cap=cap, interpret=kops.on_cpu())
-            if k_valid is None and q.shape[1] == k.shape[1]:
-                return kops.flash_attention(
-                    q, k, v, scale=scale, causal=causal, window=window,
-                    cap=cap, interpret=kops.on_cpu())
-        # fall through for unsupported combos
-    if cfg.attn_impl == "naive" or q.shape[1] == 1:
-        # decode (one query): the full-score einsum IS flash-decode FLOPs-
-        # wise, shards cleanly over a length- or head-partitioned cache
-        # (psum'd softmax reductions), and avoids lax.scan over a sharded
-        # KV axis.
-        return naive_attention(q, k, v, q_pos, k_pos, scale=scale,
-                               causal=causal, window=window, cap=cap,
-                               k_valid=k_valid, seg_q=seg_q, seg_k=seg_k)
-    return chunked_attention(q, k, v, q_pos, k_pos, scale=scale,
-                             causal=causal, window=window, cap=cap,
-                             chunk=cfg.attn_chunk, k_valid=k_valid,
-                             seg_q=seg_q, seg_k=seg_k)
+        if decode:
+            return kops.decode_attention(
+                q, k, v, q_pos[:, 0], scale=scale, window=window,
+                cap=cap, interpret=kops.on_cpu())
+        return kops.flash_attention(
+            q, k, v, scale=scale, causal=causal, window=window,
+            cap=cap, interpret=kops.on_cpu())
+    # decode (one query): the full-score einsum IS flash-decode FLOPs-wise,
+    # shards cleanly over a length- or head-partitioned cache (psum'd
+    # softmax reductions), and avoids lax.scan over a sharded KV axis.
+    return naive_attention(q, k, v, q_pos, k_pos, scale=scale,
+                           causal=causal, window=window, cap=cap,
+                           k_valid=k_valid, seg_q=seg_q, seg_k=seg_k)
 
 
 # ---------------------------------------------------------------------------
 # KV cache helpers
 # ---------------------------------------------------------------------------
 def init_kv_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int) -> dict:
-    """Zeroed cache pytree for one attention layer."""
+    """Zeroed cache pytree for one attention layer: k/v (B, H_kv, T, D),
+    head-major as attention reads them; MLA's ckv/krope (B, T, r) and the
+    positions (B, T), which have no head dim.  -1 marks an empty entry."""
     dt = L.dtype_of(cfg)
     size = min(max_len, cfg.window_size) if (kind == LOCAL_ATTN and cfg.window_size) else max_len
     if cfg.mla is not None:
@@ -277,30 +305,91 @@ def init_kv_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int) -> dict
             "pos": jnp.full((batch, size), -1, jnp.int32),
         }
     return {
-        "k": jnp.zeros((batch, size, cfg.num_kv_heads, cfg.head_dim), dt),
-        "v": jnp.zeros((batch, size, cfg.num_kv_heads, cfg.head_dim), dt),
+        "k": jnp.zeros((batch, cfg.num_kv_heads, size, cfg.head_dim), dt),
+        "v": jnp.zeros((batch, cfg.num_kv_heads, size, cfg.head_dim), dt),
         "pos": jnp.full((batch, size), -1, jnp.int32),
     }
 
 
-def _ring_write(buf: jnp.ndarray, new: jnp.ndarray, offsets: jnp.ndarray):
-    """Write `new` (B, P, ...) into ring buffer `buf` (B, T, ...) at
-    positions (offsets + arange(P)) mod T, per batch row."""
-    B, P = new.shape[:2]
-    T = buf.shape[1]
-    idx = (offsets[:, None] + jnp.arange(P)[None, :]) % T        # (B,P)
-    bidx = jnp.arange(B)[:, None].repeat(P, axis=1)
-    return buf.at[bidx, idx].set(new)
+def _ring_put(buf, new, offsets, valid, axis: int, layer=None):
+    """``buf`` with ``new`` (B, ..., S, ...) written along ``axis`` at the
+    entries (offsets[b] + i) mod T of each row b.
+
+    ``buf`` is one layer's leaf (B, ..., T, ...), or with ``layer`` a leaf
+    of a layer stack (G, B, ..., T, ...) and ``layer`` the index into it.
+    Every write is a ``dynamic_update_slice`` of one row's entries, so XLA
+    updates the buffer in place and keeps its layout.  One entry (decode)
+    is one update at offset mod T, pad or not: an inactive slot's entry
+    there takes position -1, and the slot's next entry is written over it
+    (a masked one-entry write makes XLA relay the whole cache out for
+    attention).  S > 1 entries write only where ``valid`` (B, S): what the
+    ring held under a pad entry stays.  They go through two windows of S
+    entries, each read, merged and written back: one from min(offset mod
+    T, T - S), and one from 0, which takes what wraps past T (and is
+    written back unchanged when nothing wraps).  More entries than the
+    ring holds are written T at a time, in order.
+    """
+    lead = () if layer is None else (layer,)
+    ax = len(lead) + axis
+    T, S = buf.shape[ax], new.shape[axis]
+    if S > T:
+        for lo in range(0, S, T):
+            part = jax.lax.slice_in_dim(new, lo, min(lo + T, S), axis=axis)
+            buf = _ring_put(buf, part, offsets + lo, valid[:, lo:lo + T],
+                            axis, layer)
+        return buf
+    size = (1,) * len(lead) + (1,) + new.shape[1:]
+    new = new.astype(buf.dtype)
+
+    def at(b, w):
+        idx = list(lead) + [0] * (len(size) - len(lead))
+        idx[len(lead)], idx[ax] = b, w
+        return tuple(idx)
+
+    mask_shape = [1] * len(size)
+    mask_shape[ax] = S
+    for b in range(new.shape[0]):
+        row = jax.lax.slice_in_dim(new, b, b + 1, axis=0).reshape(size)
+        a = offsets[b] % T
+        if S == 1:
+            buf = jax.lax.dynamic_update_slice(buf, row, at(b, a))
+            continue
+        # entry w + j of a window takes new[w + j - a] from a up to T, and
+        # new[w + j - a + T] below a + S - T: ``row`` rolled by a - w, or
+        # by a - w - T
+        w = jnp.minimum(a, T - S)
+        j = jnp.arange(S)
+        for w, shift, hit in ((w, a - w, w + j >= a),
+                              (0, a - T, j < a + S - T)):
+            hit = hit & jnp.roll(valid[b], shift)
+            old = jax.lax.dynamic_slice(buf, at(b, w), size)
+            buf = jax.lax.dynamic_update_slice(
+                buf, jnp.where(hit.reshape(mask_shape),
+                               jnp.roll(row, shift, axis=ax), old),
+                at(b, w))
+    return buf
 
 
 def update_cache(cache: dict, new: dict, offsets: jnp.ndarray,
-                 positions: jnp.ndarray) -> dict:
-    """new: dict of (B,P,...) tensors; positions: (B,P) absolute positions."""
+                 positions: jnp.ndarray, layer=None) -> dict:
+    """``new``: dict of rows in their leaves' layouts, S along the token
+    axis; positions: (B,S) absolute positions, -1 for pad entries, which
+    are not written.  ``layer`` indexes a layer stack (``_ring_put``)."""
     out = dict(cache)
-    for name, val in new.items():
-        out[name] = _ring_write(cache[name], val.astype(cache[name].dtype), offsets)
-    out["pos"] = _ring_write(cache["pos"], positions.astype(jnp.int32), offsets)
+    valid = positions >= 0
+    for name, val in {**new, "pos": positions.astype(jnp.int32)}.items():
+        axis = 2 if name in ("k", "v") else 1       # the length axis
+        out[name] = _ring_put(cache[name], val, offsets, valid, axis, layer)
     return out
+
+
+def layer_slice(cache: dict, layer=None) -> dict:
+    """One layer's leaves of a stacked cache (``cache`` itself if
+    ``layer`` is None)."""
+    if layer is None:
+        return cache
+    return {n: jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+            for n, x in cache.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -311,12 +400,15 @@ def attention_layer(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
                     cache: Optional[dict] = None,
                     cache_offset: Optional[jnp.ndarray] = None,
                     seg: Optional[jnp.ndarray] = None,
-                    causal: bool = True,
+                    causal: bool = True, layer=None,
                     ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """x: (B,S,d).  Train/prefill: cache None or appended-to.  Decode: S small
-    (usually 1), cache required.  positions: (B,S) or (3,B,S) for M-RoPE."""
+    (usually 1), cache required.  positions: (B,S) or (3,B,S) for M-RoPE.
+    ``layer``: ``cache`` is a layer stack and this layer is its entry
+    ``layer``; only this call's entries of it are written."""
     if cfg.mla is not None:
-        return _mla_layer(params, x, positions, cfg, cache, cache_offset)
+        return _mla_layer(params, x, positions, cfg, cache, cache_offset,
+                          layer)
     dt = x.dtype
     B, S, _ = x.shape
     pos2d = positions if positions.ndim == 2 else positions[0]
@@ -342,17 +434,19 @@ def attention_layer(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
     scale = cfg.attn_scale or (1.0 / math.sqrt(cfg.head_dim))
     window = cfg.window_size if kind == LOCAL_ATTN else 0
 
+    k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)          # (B, H_kv, S, D)
     if cache is None:
         out = _run_attention(cfg, q, k, v, pos2d, pos2d, scale=scale,
                              causal=causal, window=window,
                              cap=cfg.attn_softcap, seg_q=seg, seg_k=seg)
     else:
-        cache = update_cache(cache, {"k": k, "v": v}, cache_offset, pos2d)
-        k_valid = cache["pos"] >= 0
-        out = _run_attention(cfg, q, cache["k"], cache["v"], pos2d,
-                             cache["pos"], scale=scale, causal=causal,
-                             window=window, cap=cfg.attn_softcap,
-                             k_valid=k_valid)
+        cache = update_cache(cache, {"k": k, "v": v}, cache_offset, pos2d,
+                             layer)
+        k_pos = layer_slice(cache, layer)["pos"]
+        out = _run_attention(cfg, q, cache["k"], cache["v"], pos2d, k_pos,
+                             scale=scale, causal=causal, window=window,
+                             cap=cfg.attn_softcap, k_valid=k_pos >= 0,
+                             layer=layer)
     out = out.reshape(B, S, cfg.q_dim) @ params["wo"].astype(dt)
     return out, cache
 
@@ -382,7 +476,7 @@ def _mla_latent(params, x, cfg, angles):
     return ckv, k_rope
 
 
-def _mla_layer(params, x, positions, cfg, cache, cache_offset):
+def _mla_layer(params, x, positions, cfg, cache, cache_offset, layer=None):
     m = cfg.mla
     B, S, _ = x.shape
     dt = x.dtype
@@ -405,24 +499,23 @@ def _mla_layer(params, x, positions, cfg, cache, cache_offset):
                                       (B, S, cfg.num_heads, m.qk_rope_head_dim))],
             axis=-1)
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        out = _run_attention(cfg, q, k, v, pos2d, pos2d, scale=scale,
+        out = _run_attention(cfg, q, k.swapaxes(1, 2), v.swapaxes(1, 2),
+                             pos2d, pos2d, scale=scale,
                              causal=True, window=0, cap=0.0)
         new_cache = None
     else:
         # absorbed path: attention in latent space == MQA with Dk=rank+rope,
         # Dv=rank.  Cache stores only (ckv, k_rope): the MLA memory win.
-        cache = update_cache(cache, {"ckv": ckv, "krope": k_rope},
-                             cache_offset, pos2d)
-        new_cache = cache
+        new_cache = update_cache(cache, {"ckv": ckv, "krope": k_rope},
+                                 cache_offset, pos2d, layer)
+        c = layer_slice(new_cache, layer)
         q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
         q_abs = jnp.concatenate([q_lat, q_rope], axis=-1)
-        k_abs = jnp.concatenate([cache["ckv"], cache["krope"]],
-                                axis=-1)[:, :, None, :]
-        v_abs = cache["ckv"][:, :, None, :]
-        k_valid = cache["pos"] >= 0
-        ctx = _run_attention(cfg, q_abs, k_abs, v_abs, pos2d, cache["pos"],
+        k_abs = jnp.concatenate([c["ckv"], c["krope"]], axis=-1)[:, None]
+        v_abs = c["ckv"][:, None]                   # one head: (B, 1, T, r)
+        ctx = _run_attention(cfg, q_abs, k_abs, v_abs, pos2d, c["pos"],
                              scale=scale, causal=True, window=0, cap=0.0,
-                             k_valid=k_valid)
+                             k_valid=c["pos"] >= 0)
         out = jnp.einsum("bshr,rhd->bshd", ctx, w_uv)
     out = out.reshape(B, S, cfg.num_heads * m.v_head_dim)
     return out @ params["wo"].astype(dt), new_cache
@@ -432,12 +525,12 @@ def _mla_layer(params, x, positions, cfg, cache, cache_offset):
 # cross attention (whisper decoder)
 # ---------------------------------------------------------------------------
 def cross_attention_layer(params, x, enc_kv, cfg: ModelConfig):
-    """enc_kv: (k, v) precomputed from encoder output, (B,T,H,D)."""
+    """enc_kv: (k, v) precomputed from encoder output, (B,H,T,D)."""
     dt = x.dtype
     B, S, _ = x.shape
     k, v = enc_kv
     q = (x @ params["wq"].astype(dt)).reshape(B, S, cfg.num_heads, cfg.head_dim)
-    T = k.shape[1]
+    T = k.shape[2]
     scale = 1.0 / math.sqrt(cfg.head_dim)
     pos_q = jnp.zeros((B, S), jnp.int32)
     pos_k = jnp.zeros((B, T), jnp.int32)
@@ -447,8 +540,9 @@ def cross_attention_layer(params, x, enc_kv, cfg: ModelConfig):
 
 
 def encode_cross_kv(params, enc_out, cfg: ModelConfig):
+    """Cross K/V of the encoder output, (B,H,T,D) each."""
     dt = enc_out.dtype
     B, T, _ = enc_out.shape
     k = (enc_out @ params["wk"].astype(dt)).reshape(B, T, cfg.num_heads, cfg.head_dim)
     v = (enc_out @ params["wv"].astype(dt)).reshape(B, T, cfg.num_heads, cfg.head_dim)
-    return k, v
+    return k.swapaxes(1, 2), v.swapaxes(1, 2)

@@ -109,8 +109,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return {
         "self": jax.tree.map(
             lambda x: jnp.broadcast_to(x[None], (nl,) + x.shape).copy(), one),
-        "xk": jnp.zeros((nl, batch, enc_len, cfg.num_heads, cfg.head_dim), dt),
-        "xv": jnp.zeros((nl, batch, enc_len, cfg.num_heads, cfg.head_dim), dt),
+        "xk": jnp.zeros((nl, batch, cfg.num_heads, enc_len, cfg.head_dim), dt),
+        "xv": jnp.zeros((nl, batch, cfg.num_heads, enc_len, cfg.head_dim), dt),
     }
 
 

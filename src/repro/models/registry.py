@@ -75,8 +75,8 @@ def _build_decoder_only(cfg: ModelConfig, moe_impl: str) -> Model:
 
     def prefill(params, tokens, cache, lengths, valid=None, **kw):
         """``valid`` (B,S) bool: ragged chunk tails / inactive decode slots.
-        Pad entries are written with position -1 (never attended, ring-
-        overwritten later); recurrent blocks treat them as exactly inert."""
+        Pad entries get position -1 and are not written to the KV cache;
+        recurrent blocks treat them as exactly inert."""
         B, S = tokens.shape
         positions = transformer.make_positions(cfg, B, S, start=lengths)
         if valid is not None:

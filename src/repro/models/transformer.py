@@ -77,20 +77,40 @@ def init_layer(key, cfg: ModelConfig, kind: str, is_moe: bool) -> dict:
     return p
 
 
+def _recurrent(block, cache, layer, *args):
+    """A recurrent block on its layer's entry of a stacked ``cache``
+    (``layer`` not None): its small state is replaced whole."""
+    if layer is None:
+        return block(cache, *args)
+    out, new = block(jax.tree.map(
+        lambda c: jax.lax.dynamic_index_in_dim(c, layer, 0, False), cache),
+        *args)
+    return out, jax.tree.map(
+        lambda c, n: jax.lax.dynamic_update_index_in_dim(
+            c, n.astype(c.dtype), layer, 0), cache, new)
+
+
 def apply_layer(lp: dict, x: jnp.ndarray, cfg: ModelConfig, kind: str,
                 is_moe: bool, positions, seg, cache, offsets,
-                moe_impl: str, valid=None
+                moe_impl: str, valid=None, layer=None
                 ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
+    """``layer``: ``cache`` is a layer stack and this layer its entry
+    ``layer`` (the serving scan carries the stack and writes in place)."""
     from repro.distributed.sharding import constrain_acts
     x = constrain_acts(x)      # re-anchor batch sharding inside scan bodies
     h = L.rms_norm(x, lp["norm1"], cfg.norm_eps)
     if kind in (GLOBAL_ATTN, LOCAL_ATTN):
         mix, new_cache = A.attention_layer(lp["mixer"], h, positions, cfg,
-                                           kind, cache, offsets, seg)
+                                           kind, cache, offsets, seg,
+                                           layer=layer)
     elif kind == SSD:
-        mix, new_cache = S.ssd_block(lp["mixer"], h, cfg, cache, valid)
+        mix, new_cache = _recurrent(
+            lambda c: S.ssd_block(lp["mixer"], h, cfg, c, valid),
+            cache, layer)
     elif kind == RGLRU:
-        mix, new_cache = R.rglru_block(lp["mixer"], h, cfg, cache, valid)
+        mix, new_cache = _recurrent(
+            lambda c: R.rglru_block(lp["mixer"], h, cfg, c, valid),
+            cache, layer)
     else:
         raise ValueError(kind)
     if cfg.use_post_norms:
@@ -231,35 +251,35 @@ def forward(params: dict, cfg: ModelConfig, tokens: Optional[jnp.ndarray],
     if n_groups:
         sigs = [_layer_sig(cfg, front + j) for j in range(p)]
 
-        def group_fn(xa, gp, gc):
-            xc, aux_c = xa
+        def group_fn(xc, aux_c, gp, gc, li):
             new_cs = []
             for j in range(p):
                 kind, is_moe = sigs[j]
                 c = gc[j] if gc is not None else None
                 xc, nc, aux = apply_layer(gp[j], xc, cfg, kind, is_moe,
                                           positions, seg, c, offsets,
-                                          moe_impl, valid)
+                                          moe_impl, valid, layer=li)
                 aux_c = aux_c + aux
                 new_cs.append(nc)
-            return (xc, aux_c), tuple(new_cs)
+            return xc, aux_c, tuple(new_cs)
 
-        group_fn = _remat(group_fn, cfg)
-
-        def scan_body(carry, xs):
-            gp, gc = xs
-            (xc, aux_c), new_cs = group_fn(carry, gp, gc)
-            return (xc, aux_c), new_cs
-
-        gc_xs = cache["groups"] if cache is not None else None
-        if gc_xs is None:
-            (x, aux_total), new_groups = jax.lax.scan(
-                lambda ca, gp: scan_body(ca, (gp, None)),
+        if cache is None:
+            train_fn = _remat(
+                lambda xa, gp: group_fn(*xa, gp, None, None)[:2], cfg)
+            (x, aux_total), _ = jax.lax.scan(
+                lambda xa, gp: (train_fn(xa, gp), None),
                 (x, aux_total), params["groups"])
-            new_groups = None
         else:
-            (x, aux_total), new_groups = jax.lax.scan(
-                scan_body, (x, aux_total), (params["groups"], gc_xs))
+            # the stacked cache rides in the carry: each layer writes its
+            # new entries into it in place and reads its own entry of it
+            def step(carry, gp):
+                xc, aux_c, gc, li = carry
+                xc, aux_c, gc = group_fn(xc, aux_c, gp, gc, li)
+                return (xc, aux_c, gc, li + 1), None
+
+            (x, aux_total, new_groups, _), _ = jax.lax.scan(
+                step, (x, aux_total, cache["groups"], jnp.int32(0)),
+                params["groups"])
 
     new_tail = []
     for j, lp in enumerate(params["tail"]):

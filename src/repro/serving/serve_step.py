@@ -5,8 +5,9 @@ programs the engine (and the dry-run) calls:
 
   * ``prefill_chunk(params, cache, tokens(B,C), lengths(B,), valid_n(B,))``
       -> (next_token (B,), last_logits (B,V), cache)
-    Ragged tails are exact: pad entries are written with position -1 and
-    recurrent state is untouched past valid_n (see models' ``valid`` path).
+    Ragged tails are exact: pad entries are not written to the KV cache
+    and recurrent state is untouched past valid_n (see models' ``valid``
+    path).
     The dry-run lowers it; the engine's executor runs ``prefill_rows``.
   * ``prefill_rows(params, cache, tokens(P,C), lengths(P,), valid_n(P,),
     slots(P,))`` -> (next_token (P,), last_logits (P,V), cache), P =
@@ -179,8 +180,7 @@ def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
         full = cache
         if slots is not None:
             cache = _rows(_take_rows(full, slots))
-        B, C = tokens.shape
-        valid = jnp.arange(C)[None, :] < valid_n[:, None]
+        valid = jnp.arange(tokens.shape[1])[None, :] < valid_n[:, None]
         logits, new = model.prefill(params, tokens, cache, lengths,
                                     valid=valid)
         last = jnp.take_along_axis(
@@ -189,14 +189,9 @@ def build_serve_fns(cfg: ModelConfig, mesh: Optional[Mesh] = None, *,
         nxt = sample(last, temperature=temperature)
         if slots is None:
             return nxt, last, new
-        adv = valid_n > 0
-
-        def keep(path, n, o):
-            b = _batch_leaf(path, n)
-            shape = (1,) * b + (B,) + (1,) * (n.ndim - b - 1)
-            return jnp.where(adv.reshape(shape), n, o)
-        new = _rows(jax.tree_util.tree_map_with_path(keep, new, cache))
-        return nxt, last, _put_rows(full, slots, new)
+        # a row with valid_n == 0 comes back as it was read: pad entries
+        # are not written, and recurrent state is inert on them
+        return nxt, last, _put_rows(full, slots, _rows(new))
 
     def _decode(params, cache, tokens, lengths, active):
         logits, cache = model.decode_step(

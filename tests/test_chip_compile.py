@@ -196,3 +196,121 @@ def test_qwen3_prefill_rows_compiles(topo, chips):
     cache_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(cache)) // chips
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+
+
+def _computations(text):
+    """{name: (is_entry, [instruction lines])} of an HLO module's text,
+    without the bodies of fusions (what runs inside a fusion is never a
+    buffer of its own)."""
+    import re
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(ENTRY )?%(\S+) .*\{\s*$", line)
+        if m and not line.startswith(" "):
+            cur = m.group(2)
+            comps[cur] = (bool(m.group(1)), [])
+        elif cur and line.strip().startswith(("%", "ROOT %")):
+            comps[cur][1].append(line)
+    fused = {c for _, lines in comps.values() for line in lines
+             if " fusion(" in line
+             for c in re.findall(r"calls=%([\w.\-]+)", line)}
+    return {c: v for c, v in comps.items() if c not in fused}
+
+
+def _buffers(text):
+    """(in_loop, name, opcode, shape, bytes) of every array an HLO module
+    materializes outside fusion bodies; ``in_loop``: not in the entry
+    computation (a while body, here the layer and attention loops)."""
+    import re
+    width = {"bf16": 2, "f32": 4, "s32": 4, "pred": 1}
+    out = []
+    for entry, lines in _computations(text).values():
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
+                         r"([\w-]+)\(", line)
+            if not m or m.group(2) not in width:
+                continue
+            shape = tuple(int(d) for d in m.group(3).split(",") if d)
+            target = re.search(r'custom_call_target="(\w+)"', line)
+            op = m.group(4) + (":" + target.group(1) if target else "")
+            out.append((not entry, m.group(1), op, shape,
+                        width[m.group(2)] * int(np.prod(shape))))
+    return out
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("program", ["decode", "prefill_rows"])
+def test_serving_programs_write_the_kv_cache_in_place(topo, program, chips):
+    """Qwen3-8B at published widths, 4 layers, bf16, 8 slots x 2048, on
+    one chip and tensor parallel on ``(data=1, model=4)``.  The layer scan
+    carries the stacked KV cache: the only arrays of a layer's K/V size in
+    the layer loops are the in-place ``dynamic-update-slice``s of the
+    carried buffers (no per-layer slice, relayout or scan output), no copy
+    of the whole cache is made, and the exchange carries activations
+    only.  Decode's temporaries stay under one layer's K+V.  (Prefill's
+    hold by design the chunk's f32 logits and the rows it gathers, so no
+    such bound fits it.)"""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
+    from repro.serving.serve_step import build_serve_fns
+    cfg = dataclasses.replace(get_config("qwen3-8b"), num_layers=4,
+                              param_dtype="bfloat16", tie_embeddings=False)
+    B, L, C, P = 8, 2048, 256, 2
+    mesh = (make_mesh((1, 4), ("data", "model"), devices=topo.devices)
+            if chips == 4 else None)
+    fns = build_serve_fns(cfg, mesh, batch=B, max_len=L, prefill_chunk=C,
+                          prefill_width=P)
+    params = jax.eval_shape(fns.model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(fns.model.init_cache, B, L))
+    if mesh is None:
+        one = SingleDeviceSharding(topo.devices[0])
+        params, cache = _place(params, one), _place(cache, one)
+        row = one
+    else:
+        params, cache = (
+            jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=s), t, sh)
+            for t, sh in ((params, fns.param_shardings),
+                          (cache, fns.cache_shardings)))
+        row = NamedSharding(mesh, PartitionSpec())
+    if program == "decode":
+        i32 = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=row)
+        act = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=row)
+        compiled = fns.decode.lower(params, cache, i32, i32, act).compile()
+        rows, acts = B, {f"bf16[{B},1,{cfg.d_model}]"}
+    else:
+        toks = jax.ShapeDtypeStruct((P, C), jnp.int32, sharding=row)
+        i32 = jax.ShapeDtypeStruct((P,), jnp.int32, sharding=row)
+        compiled = fns.prefill_rows.lower(params, cache, toks, i32, i32,
+                                          i32).compile()
+        rows, acts = P, {f"bf16[{P},{C},{cfg.d_model}]"}
+    text = compiled.as_text()
+    kv = cache["groups"][0]["k"]                     # (layers, B, H, L, D)
+    assert kv.shape == (4, B, cfg.num_kv_heads, L, cfg.head_dim)
+    heads = cfg.num_kv_heads // chips
+    leaf = B * heads * L * cfg.head_dim * 2          # one layer's K, a chip
+    stacked = (4, B, heads, L, cfg.head_dim)
+    # (the async moves between memory spaces that the compiler adds at this
+    # depth, copy-/slice-done and the slices' ConcatBitcast, keep the
+    # layout; at the cells' 16 and 36 layers it adds none)
+    in_place = {"dynamic-update-slice", "get-tuple-element", "parameter",
+                "bitcast", "tuple", "copy-done", "slice-done",
+                "custom-call:ConcatBitcast"}
+    for in_loop, name, op, shape, nbytes in _buffers(text):
+        if in_loop and L in shape and nbytes >= leaf * rows // B:
+            assert op in in_place, (name, op, shape)
+        if op == "copy":
+            assert shape != stacked, (name, shape)
+    if chips == 4:
+        import re
+        assert set(re.findall(r"(\w+\[[\d,]+\])\S* all-reduce\(", text)) \
+            == acts
+        for size in re.findall(r"\w+\[([\d,]+)\]\S* all-gather\(", text):
+            assert np.prod([int(d) for d in size.split(",")]) <= 64, size
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache)) // chips
+    assert mem.alias_size_in_bytes >= cache_bytes
+    if program == "decode":
+        assert mem.temp_size_in_bytes < 2 * leaf

@@ -34,7 +34,8 @@ def test_flash_attention_sweep(dtype, S, Hq, Hkv, D, win, cap):
     k = _rand(ks[1], (B, S, Hkv, D), dtype)
     v = _rand(ks[2], (B, S, Hkv, D), dtype)
     scale = 1.0 / np.sqrt(D)
-    out = ops.flash_attention(q, k, v, scale=scale, causal=True, window=win,
+    out = ops.flash_attention(q, k.swapaxes(1, 2), v.swapaxes(1, 2),
+                              scale=scale, causal=True, window=win,
                               cap=cap, bq=64, bk=64, interpret=True)
     want = ref.flash_attention_ref(q, k, v, scale=scale, causal=True,
                                    window=win, cap=cap)
@@ -48,8 +49,8 @@ def test_flash_attention_noncausal():
     q = _rand(ks[0], (1, 64, 2, 32), jnp.float32)
     k = _rand(ks[1], (1, 96, 2, 32), jnp.float32)
     v = _rand(ks[2], (1, 96, 2, 32), jnp.float32)
-    out = ops.flash_attention(q, k, v, scale=0.2, causal=False,
-                              interpret=True)
+    out = ops.flash_attention(q, k.swapaxes(1, 2), v.swapaxes(1, 2),
+                              scale=0.2, causal=False, interpret=True)
     # non-causal oracle: plain softmax over all keys
     s = jnp.einsum("bshd,bthd->bhst", q * 0.2, k)
     p = jax.nn.softmax(s, -1)
@@ -75,8 +76,9 @@ def test_decode_attention_sweep(dtype, T, Hq, Hkv, D, win, bk):
     v = _rand(ks[2], (B, T, Hkv, D), dtype)
     lens = jnp.array([1, T // 2, T], jnp.int32)
     scale = 1.0 / np.sqrt(D)
-    out = ops.decode_attention(q, k, v, lens, scale=scale, window=win,
-                               bk=bk, interpret=True)
+    out = ops.decode_attention(q, k.swapaxes(1, 2), v.swapaxes(1, 2), lens,
+                               scale=scale, window=win, bk=bk,
+                               interpret=True)
     want = ref.decode_attention_ref(q, k, v, lens, scale=scale, window=win)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                 - want.astype(jnp.float32))))

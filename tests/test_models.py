@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _kv import token_major
 from repro.configs import (SHAPES, cell_supported, get_config, list_archs,
                            param_count, smoke_config)
 from repro.models.registry import build_model, input_names
@@ -128,7 +129,7 @@ def test_local_attention_ring_cache_is_o_window():
     cfg = smoke_config("gemma2-27b")
     m = build_model(cfg)
     cache = m.init_cache(2, 1024)
-    leaves = jax.tree.leaves(cache)
+    leaves = jax.tree.leaves(token_major(cache))
     # at least one leaf (local layers) capped at window, one at 1024
     sizes = {x.shape[-3] if x.ndim >= 3 else x.shape[-1] for x in leaves
              if x.ndim >= 2}
@@ -200,3 +201,68 @@ def test_moe_grouped_dispatch_padding_exact():
     y_big, _ = M.apply_moe_gshard(params, x, cfg, group_size=4096)
     # generous capacity => no drops in either grouping => identical
     assert float(jnp.max(jnp.abs(y_small - y_big))) < 1e-4
+
+
+@pytest.mark.parametrize("T,S,stacked", [
+    (16, 1, False), (16, 1, True), (16, 5, False), (16, 5, True),
+    (16, 16, True), (16, 23, False), (8, 3, True)])
+def test_ring_put_writes_each_entry_at_offset_mod_length(T, S, stacked):
+    """The KV cache write against a plain loop: entry i of row b lands at
+    (offset_b + i) mod T, in order, where it is valid; a one-entry write
+    lands whatever its validity.  Rows wrap the ring's end, start on it,
+    stay inside it, and (S > T) go round it more than once; with a layer
+    stack only the indexed layer changes."""
+    from repro.models.attention import _ring_put
+    rng = np.random.default_rng(T * 100 + S)
+    B, H, D, G, layer = 3, 2, 4, 3, 1
+    offsets = np.asarray([T - 2, 0, 3 * T + 5], np.int32)
+    valid = np.arange(S)[None] < np.asarray([S, max(S - 2, 1), 0])[:, None]
+    for shape, axis in (((B, H, T, D), 2), ((B, T), 1)):
+        buf = rng.normal(size=((G,) + shape) if stacked else shape)
+        new = rng.normal(size=shape[:axis] + (S,) + shape[axis + 1:])
+        want = buf.copy()
+        one = want[layer] if stacked else want
+        for b in range(B):
+            for i in range(S):
+                if S == 1 or valid[b, i]:
+                    idx = [b] + [slice(None)] * (len(shape) - 1)
+                    src = list(idx)
+                    idx[axis] = (offsets[b] + i) % T
+                    src[axis] = i
+                    one[tuple(idx)] = new[tuple(src)]
+        got = _ring_put(jnp.asarray(buf, jnp.float32),
+                        jnp.asarray(new, jnp.float32), jnp.asarray(offsets),
+                        jnp.asarray(valid), axis,
+                        jnp.int32(layer) if stacked else None)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=1e-6)
+
+
+def test_encdec_served_chunks_match_the_forward():
+    """Whisper's decoder served from its cache (the encoder's K/V filled
+    by the first chunk, then a ragged chunk and three decode steps) gives
+    the logits of the forward over the whole sequence."""
+    import dataclasses
+    cfg = dataclasses.replace(smoke_config("whisper-large-v3"),
+                              dtype="float32")
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    B, C = 2, 8
+    frames = jax.random.normal(jax.random.PRNGKey(1),
+                               (B, cfg.num_audio_frames, cfg.d_model))
+    seq = jax.random.randint(jax.random.PRNGKey(2), (B, 16), 0,
+                             cfg.vocab_size)
+    want, _ = m.forward(params, {"frames": frames, "tokens": seq})
+    cache = m.init_cache(B, 32)
+    lengths = jnp.zeros(B, jnp.int32)
+    got, cache = m.prefill(params, seq[:, :C], cache, lengths, frames=frames)
+    chunk = jnp.zeros((B, C), jnp.int32).at[:, :5].set(seq[:, C:C + 5])
+    valid = jnp.broadcast_to(jnp.arange(C)[None] < 5, (B, C))
+    logits, cache = m.prefill(params, chunk, cache, lengths + C, valid=valid)
+    got = [got[:, -1], logits[:, 4]]
+    for n in range(C + 5, 16):
+        logits, cache = m.decode_step(params, seq[:, n:n + 1], cache,
+                                      jnp.full(B, n, jnp.int32))
+        got.append(logits[:, 0])
+    want = np.asarray(want[:, [C - 1] + list(range(C + 4, 16))])
+    np.testing.assert_allclose(np.stack(got, 1), want, rtol=0,
+                               atol=1e-4 * np.abs(want).max())

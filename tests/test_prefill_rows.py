@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import smoke_config
+from _kv import token_major
+from repro.configs import list_archs, smoke_config
 from repro.core.slo import SLOPolicy
 from repro.serving.engine import Engine, EngineConfig, ModelExecutor
 from repro.serving.request import Request, RequestStatus
@@ -247,3 +248,97 @@ def test_engine_serves_the_tokens_of_the_full_batch_program():
                         prefill_slots_per_step=2, max_tenants=2)
     assert _serve(ModelExecutor(CFG, ecfg, rng_seed=0)) == _serve(
         _FullBatch(CFG, ecfg, rng_seed=0))
+
+
+# ---------------------------------------------------------------------------
+# served tokens against the forward without a cache
+# ---------------------------------------------------------------------------
+DECODE_ARCHS = [a for a in list_archs()
+                if not smoke_config(a).is_encoder_decoder]
+
+
+def _serve_two_slots(fns, params, prompts, C, steps):
+    """Prefill ``prompts`` (slot -> tokens) two rows at a time in C-token
+    chunks, then decode ``steps`` tokens for every slot at once.  Returns
+    the cache, each slot's lengths and each slot's (token, logits) per
+    served position."""
+    slots = sorted(prompts)
+    lengths = np.zeros(B, np.int32)
+    served = {s: [] for s in slots}
+    cache = fns.init_cache()
+    while any(lengths[s] < len(prompts[s]) for s in slots):
+        toks = np.zeros((2, C), np.int32)
+        n = np.zeros(2, np.int32)
+        for r, s in enumerate(slots):
+            part = prompts[s][lengths[s]:lengths[s] + C]
+            toks[r, :part.size], n[r] = part, part.size
+        nxt, last, cache = fns.prefill_rows(
+            params, cache, toks, lengths[slots], n,
+            np.asarray(slots, np.int32))
+        for r, s in enumerate(slots):
+            lengths[s] += n[r]
+            if n[r] and lengths[s] == len(prompts[s]):
+                served[s].append((int(nxt[r]), np.asarray(last[r])))
+    active = np.zeros(B, bool)
+    active[slots] = True
+    for _ in range(steps):
+        toks = np.zeros(B, np.int32)
+        for s in slots:
+            toks[s] = served[s][-1][0]
+        nxt, logits, cache = fns.decode(params, cache, toks, lengths, active)
+        for s in slots:
+            served[s].append((int(nxt[s]), np.asarray(logits[s])))
+        lengths = lengths + active
+    return cache, lengths, served
+
+
+@pytest.mark.parametrize("arch", DECODE_ARCHS)
+def test_served_tokens_match_the_forward_without_cache(arch):
+    """``prefill_rows`` (two slots a call, one running out first) then six
+    decode steps give each slot the tokens and logits of the plain
+    forward over its whole sequence.  The smoke local-window layers keep
+    32 entries: the 30-token prompt's last chunk [24, 36) holds 6 tokens
+    and 6 pad entries that reach past the ring's end, and its last four
+    decode steps wrap the ring.  Served again in 7-token chunks, every
+    slot reads back the same cache, token-major, each position at its
+    ring entry."""
+    cfg = _arch(arch)
+    steps = 6
+    rng = np.random.default_rng(7)
+    prompts = {1: rng.integers(1, cfg.vocab_size, 30).astype(np.int32),
+               3: rng.integers(1, cfg.vocab_size, 20).astype(np.int32)}
+    runs = []
+    for C in (12, 7):
+        fns = build_serve_fns(cfg, None, batch=B, max_len=T,
+                              prefill_chunk=C, prefill_width=2)
+        params = fns.init_params(jax.random.PRNGKey(0))
+        runs.append(_serve_two_slots(fns, params, prompts, C, steps))
+    (cache, lengths, served), (cache7, lengths7, served7) = runs
+
+    for s, prompt in prompts.items():
+        seq = np.concatenate([prompt, [t for t, _ in served[s][:-1]]])
+        ref, _ = fns.model.forward(params, {"tokens": jnp.asarray(seq[None])})
+        ref = np.asarray(ref[0, len(prompt) - 1:])
+        for run in (served, served7):
+            assert [t for t, _ in run[s]] == ref.argmax(-1).tolist()
+            np.testing.assert_allclose(np.stack([lg for _, lg in run[s]]),
+                                       ref, rtol=0,
+                                       atol=1e-4 * np.abs(ref).max())
+        assert lengths[s] == lengths7[s] == len(seq)
+
+    rows = sorted(prompts)
+    for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(
+                _rows(token_major(cache), rows))[0],
+            jax.tree.leaves(_rows(token_major(cache7), rows))):
+        if str(path[-1].key) == "pos":                  # (..., 2, W)
+            W = a.shape[-1]
+            for i, s in enumerate(rows):
+                want = [max((p for p in range(lengths[s]) if p % W == t),
+                            default=-1) for t in range(W)]
+                got = a[..., i, :].reshape(-1, W).tolist()
+                assert got == [want] * len(got)
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, rtol=0,
+                                       atol=1e-4 * max(np.abs(b).max(), 1))

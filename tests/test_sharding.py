@@ -89,18 +89,31 @@ def test_batch_axes_fallback(host_mesh, pod_mesh):
 
 
 def test_cache_pspecs_head_or_length_over_model(host_mesh):
-    """kv=8 over model=4 -> heads shard; kv=2 over model=4 -> length
-    shards instead (the qwen3-on-16-way case, scaled down)."""
+    """k/v are stored head-major, (..., B, H_kv, T, D).  kv=8 over model=4
+    -> heads shard; kv=2 over model=4 -> length shards instead (the
+    qwen3-on-16-way case, scaled down); ``shard_length`` puts the length
+    on data and keeps the heads on model.  The positions follow the
+    length: whole on each model shard when the heads are split there."""
     cfg = get_config("qwen3-8b")          # kv 8 % 4 == 0 on host mesh
     m = build_model(cfg)
     sds = jax.eval_shape(lambda: m.init_cache(8, 64))
     specs = SH.cache_pspecs(cfg, sds, host_mesh)
     flat = jax.tree_util.tree_flatten_with_path(
         specs, is_leaf=lambda x: isinstance(x, P))[0]
-    kv = [s for path, s in flat
+    kv = [tuple(s) for path, s in flat
           if str(path[-1].key) in ("k", "v")]
-    assert all("model" in tuple(s) for s in kv)
-    assert all("data" in tuple(s) for s in kv)
+    assert kv and all(t[-3] == "model" for t in kv)       # heads
+    assert all(t[-4] == "data" for t in kv)               # slots
+    assert all(t[-2] is None for t in kv)                 # length whole
+    pos = [tuple(s) for path, s in flat if str(path[-1].key) == "pos"]
+    assert pos and all("model" not in t for t in pos)     # whole per chip
+
+    specs_len = SH.cache_pspecs(cfg, sds, host_mesh, shard_length=True)
+    for path, s in jax.tree_util.tree_flatten_with_path(
+            specs_len, is_leaf=lambda x: isinstance(x, P))[0]:
+        if str(path[-1].key) in ("k", "v"):
+            t = tuple(s)
+            assert t[-2] == "data" and t[-3] == "model", s
 
     import dataclasses
     cfg2 = dataclasses.replace(cfg, num_kv_heads=2)   # 2 % 4 != 0
@@ -113,8 +126,10 @@ def test_cache_pspecs_head_or_length_over_model(host_mesh):
         if str(path[-1].key) in ("k", "v"):
             t = tuple(s)
             assert "model" in t, s
-            # length dim (index ndim-3) carries it, not the head dim
-            assert t[-3] == "model"
+            # the length dim (index ndim-2) carries it, not the head dim
+            assert t[-2] == "model" and t[-3] is None
+        if str(path[-1].key) == "pos":                 # and the positions'
+            assert tuple(s)[-1] == "model", s
 
 
 def test_cache_pspecs_long_context_shards_length_over_data(host_mesh):
